@@ -332,8 +332,8 @@ def _smoke_kill_resume_step(ctx):
     in a fresh process, require bitwise-identical deliverables."""
     resumed_step = _smoke_kill_resume(ctx["grid_kw"])
     return [("smoke.kill_resume_parity", 1.0,
-             f"SIGKILL at chunk 2 -> resumed from step {resumed_step} "
-             f"bitwise-identical")]
+             f"CPU child: SIGKILL at chunk 2 -> resumed from step "
+             f"{resumed_step} bitwise-identical")]
 
 
 def _smoke_service(ctx):
@@ -406,8 +406,8 @@ def _smoke_service(ctx):
         ("smoke.service_backpressure", 1.0,
          "over-capacity submit rejected; admitted work exact"),
         ("smoke.service_kill_resume", 1.0,
-         f"server SIGKILL -> restart resumed from step {resumed_step} "
-         f"bitwise-identical"),
+         f"CPU child: server SIGKILL -> restart resumed from step "
+         f"{resumed_step} bitwise-identical"),
     ]
 
 
@@ -438,7 +438,9 @@ def _smoke_net_kill_reconnect(ctx):
                    detnet_fps=tuple(float(f) for f in range(5, 105, 5)))
     req = SweepRequest(grid=grid_kw, track="all", chunk_size=31, top_k=4)
 
-    env = dict(os.environ)
+    # The child is a CPU rehearsal: this process already holds JAX, and
+    # on a chip host a second process would contend for the chip.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=1")
     env["PYTHONPATH"] = os.pathsep.join(
@@ -510,8 +512,9 @@ def _smoke_net_kill_reconnect(ctx):
             server_b.send_signal(signal.SIGTERM)
             server_b.wait(60)
     return [("smoke.net_kill_reconnect", 1.0,
-             f"server SIGKILL -> client reconnect + dedupe resumed from "
-             f"step {int(res.stats['resumed_from_step'])} bitwise")]
+             f"CPU child: server SIGKILL -> client reconnect + dedupe "
+             f"resumed from step {int(res.stats['resumed_from_step'])} "
+             f"bitwise")]
 
 
 def _smoke_net_fairness(ctx):
@@ -799,7 +802,9 @@ assert np.array_equal(df.indices, sf.indices)
 assert np.array_equal(df.values, sf.values)
 print(json.dumps({"resumed_from_step": res.stats["resumed_from_step"]}))
 """
-        env = dict(os.environ)
+        # CPU children: this process already holds JAX (see
+        # _smoke_net_kill_reconnect).
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         # Pin the child to one device so the dispatch geometry (and
         # with it the kill_at trigger) is independent of any inherited
         # ``XLA_FLAGS`` — appending wins, the last flag takes effect.
@@ -868,7 +873,9 @@ assert np.array_equal(res.front_indices, ref.front_indices)
 assert np.array_equal(res.front_values, ref.front_values)
 print(json.dumps({"resumed_from_step": res.stats["resumed_from_step"]}))
 """
-        env = dict(os.environ)
+        # CPU children: this process already holds JAX (see
+        # _smoke_net_kill_reconnect).
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         # Pin the child to one device (see _smoke_kill_resume_step):
         # the kill_at trigger depends on the dispatch geometry, which
         # inherited ``XLA_FLAGS`` would otherwise change.
@@ -897,6 +904,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="fast streaming/dense parity gate (CI)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         print("name,value,derived")
         t0 = time.time()

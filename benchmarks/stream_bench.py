@@ -189,7 +189,9 @@ def _worker(mode: str, n: int, scan: int | None = None,
 
 def _spawn(mode: str, n: int, scan: int | None = None,
            ckpt_every_s: float | None = None) -> dict:
-    env = dict(os.environ)
+    # Workers are CPU rehearsals: the parent already ran JAX, and on a
+    # chip host a child would contend for the chip it holds.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.pathsep.join(
         [str(SRC)] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep)
                       if p])
@@ -400,7 +402,8 @@ def rows():
     if s_small and s_big:
         out.append(("stream.rss_growth_1e5_to_1e7", s_big / s_small,
                     "bounded host memory: peak RSS ratio across 100x grid"))
-    return out
+    # Every timed number above comes from a JAX_PLATFORMS=cpu worker.
+    return [(name, val, f"CPU; {derived}") for name, val, derived in out]
 
 
 def main() -> None:

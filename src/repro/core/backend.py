@@ -38,7 +38,7 @@ here::
   ``"pallas"`` backend registers lazily on first request from
   :mod:`repro.kernels.sweep_grid`.
 
-Everything here runs under the caller's scoped ``enable_x64`` context;
+Everything here runs under the caller's scoped ``jax.enable_x64`` context;
 flat indices are int64 whenever the index space could overflow int32
 (see :func:`repro.core.sweep.decode_flat_index`).
 """

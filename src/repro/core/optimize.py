@@ -11,7 +11,7 @@ straight through the analytical model:
   closed over one *discrete* configuration (cut, nodes, weight memory,
   camera count).
 * :func:`evaluate` / :func:`gradient` / :func:`evaluate_fields` — scalar
-  conveniences that scope ``enable_x64`` for you (the kernel runs in
+  conveniences that scope ``jax.enable_x64`` for you (the kernel runs in
   float64, same as the grid engine).
 * :func:`optimize_knobs` — projected Adam over box-bounded knobs.  Knobs
   are normalized to [0, 1] over their bounds so one learning rate serves
@@ -39,7 +39,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from ..optim import adamw
 from . import arrays as A
@@ -141,9 +140,9 @@ def objective_fn(objective="avg_power", **config) -> Callable:
     validated eagerly — an MRAM request on a node with no test vehicle
     raises here, mirroring the scalar path, instead of yielding NaN.
 
-    Call (and differentiate) the result under ``jax.experimental
-    .enable_x64()`` — or use :func:`evaluate`/:func:`gradient`, which
-    scope it for you.
+    Call (and differentiate) the result under
+    ``jax.enable_x64(True)`` — or use :func:`evaluate`/:func:`gradient`,
+    which scope it for you.
     """
     w = _weights(objective)
     r = _resolve(config)
@@ -161,7 +160,7 @@ def evaluate(objective="avg_power", knobs: Mapping[str, float] | None = None,
              **config) -> float:
     """Scalarized objective value at one knob setting (float64)."""
     f = objective_fn(objective, **config)
-    with enable_x64():
+    with jax.enable_x64(True):
         return float(f({k: jnp.asarray(float(v))
                         for k, v in (knobs or {}).items()}))
 
@@ -173,7 +172,7 @@ def evaluate_fields(knobs: Mapping[str, float] | None = None,
     plumbing as the optimizer (including custom ``model=`` lowerings)."""
     r = _resolve(config)
     kernel = S.config_kernel(r.M)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = kernel(*r.kernel_kwargs(
             {k: jnp.asarray(float(v)) for k, v in (knobs or {}).items()}))
         return {k: float(v) for k, v in out.items()}
@@ -186,7 +185,7 @@ def gradient(objective="avg_power", knobs: Mapping[str, float] | None = None,
     their config-pinned or default values, when ``knobs`` is omitted)."""
     f = objective_fn(objective, **config)
     at = dict(knobs) if knobs is not None else _resolve(config).base_knobs
-    with enable_x64():
+    with jax.enable_x64(True):
         v, g = jax.value_and_grad(f)(
             {k: jnp.asarray(float(x)) for k, x in at.items()})
     return float(v), {k: float(x) for k, x in g.items()}
@@ -238,7 +237,7 @@ def optimize_knobs(bounds: Mapping[str, tuple[float, float]],
     cfg = adamw.AdamWConfig(lr=lr, warmup_steps=0, total_steps=steps,
                             min_lr_ratio=0.02, weight_decay=0.0)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         def denorm(x):
             return {n: lo[n] + x[n] * (hi[n] - lo[n]) for n in names}
 

@@ -56,7 +56,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from . import arrays as A
 from . import sweep as SW
@@ -455,7 +454,7 @@ def simulate_session(scenarios="steady", trace: str | None = None,
     if trace is None:
         trace = sset.traces[0].name
     sset = sset.only(trace)
-    with enable_x64():
+    with jax.enable_x64(True):
         S = A.stack_model_arrays((A.model_arrays(detnet, keynet),))
         stack = scenario_stack(S, sset)
         base_fn = SW._make_config_fn(S)

@@ -431,6 +431,9 @@ class SweepService:
                  workers: int = 0,
                  worker_ttl_s: float = 10.0,
                  lease_splits: Optional[int] = None):
+        if workers > 0:
+            from ..runtime import workers as WK
+            WK.require_cpu_pool()
         self._own_spool = workers > 0 and spool_dir is None
         if self._own_spool:
             import tempfile
@@ -1116,9 +1119,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     """Spool-backed sweep server.  Batch mode (default): recover + run
     journaled requests, then requests from ``--requests`` (a JSON-lines
     file of :meth:`SweepRequest.to_json` payloads), print one JSON
-    summary per finished request plus the final health snapshot.
-    Networked mode (``--listen HOST:PORT`` or ``--unix PATH``): serve
-    the framed-JSON protocol of :mod:`repro.runtime.transport` until
+    summary per finished request plus the final health snapshot, and
+    exit 1 when any request failed (or timed out).  Networked mode
+    (``--listen HOST:PORT`` or ``--unix PATH``): serve the framed-JSON
+    protocol of :mod:`repro.runtime.transport` until
     SIGTERM/SIGINT, then drain gracefully."""
     ap = argparse.ArgumentParser(
         prog="python -m repro.service",
@@ -1163,6 +1167,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "is parsed)")
     args = ap.parse_args(argv)
 
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     svc = SweepService(spool_dir=args.spool, capacity=args.capacity,
                        checkpoint_every_steps=args.checkpoint_every_steps,
                        workers=args.workers,
@@ -1177,6 +1183,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if args.listen or args.unix:
         return _serve(svc, args.listen, args.unix,
                       auth_token=args.auth_token)
+    failed = 0
     try:
         tickets = svc.tickets()     # recovered work first
         if args.requests:
@@ -1191,12 +1198,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             try:
                 t.result(args.timeout_s)
             except Exception:
-                pass
+                failed += 1
             print(json.dumps(_result_summary(t)))
         print(json.dumps({"health": svc.health()}))
     finally:
         svc.close()
-    return 0
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":      # pragma: no cover

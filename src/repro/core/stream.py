@@ -87,6 +87,7 @@ the ``benchmarks/run.py --smoke`` CI gate.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import threading
@@ -101,7 +102,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from ..checkpoint import CheckpointManager
 from ..runtime.elastic import drop_worker
@@ -142,10 +142,7 @@ DEFAULT_CHECKPOINT_EVERY_S = 30.0
 #: Failures that trigger a pipeline restart from the last consistent
 #: snapshot (vs the in-place retry of pre-dispatch transient faults and
 #: the elastic replan of device loss).
-try:
-    _RESTARTABLE: tuple = (TransientDeviceError, jax.errors.JaxRuntimeError)
-except AttributeError:  # pragma: no cover - jax without jax.errors
-    _RESTARTABLE = (TransientDeviceError,)
+_RESTARTABLE = (TransientDeviceError, jax.errors.JaxRuntimeError)
 
 
 # ---------------------------------------------------------------------------
@@ -1018,7 +1015,14 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
         "chunks_reissued": 0.0, "elastic_replans": 0.0,
         "stragglers": 0.0, "step_timeouts": 0.0,
     }
-    with enable_x64():
+    def on_devices():
+        # An explicit devices= list pins every device op of the run —
+        # probe, chunk steps and survivor-overflow fallback — so a
+        # reference run on the CPU never evaluates anything elsewhere.
+        return (jax.default_device(dev_list[0]) if plan.explicit_devices
+                else contextlib.nullcontext())
+
+    with jax.enable_x64(True), on_devices():
         seed_signed, hist_edges, axis_valid = _probe(
             S, axis_vals, full_shape, n_total, objectives, sign, cons,
             hist_bins, hist_ranges)
@@ -1107,7 +1111,14 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
             # replicated up front so the pmap path never re-shards an
             # argument per step.
             if n_dev > 1:
-                put = (lambda t: jax.device_put_replicated(t, dev_list))
+                # The pmap layout: a leading device axis holding one
+                # replica per device.
+                rep = jax.sharding.NamedSharding(
+                    jax.sharding.Mesh(np.asarray(dev_list), ("dev",)),
+                    jax.sharding.PartitionSpec("dev"))
+                put = (lambda t: jax.device_put(jax.tree_util.tree_map(
+                    lambda x: np.broadcast_to(x, (n_dev,) + np.shape(x)),
+                    t), rep))
             else:
                 dev_target = dev_list[0] if plan.explicit_devices else None
                 put = (lambda t: jax.device_put(t, dev_target))
@@ -1409,7 +1420,7 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
                 def produce():
                     nonlocal carry
                     try:
-                        with enable_x64():
+                        with jax.enable_x64(True), on_devices():
                             for si in range(n_steps):
                                 if stop.is_set():
                                     break

@@ -36,7 +36,7 @@ Grid axes of :func:`evaluate_grid` (cartesian product, in order)::
 Configurations that are physically invalid (MRAM weight memory on a node
 with no MRAM test vehicle, with an on-sensor deployment present) evaluate
 to NaN rather than raising, so a dense grid can mix valid and invalid
-corners.  All arithmetic runs in float64 (scoped ``enable_x64`` — the
+corners.  All arithmetic runs in float64 (scoped ``jax.enable_x64`` — the
 global JAX config is left untouched).
 """
 
@@ -53,7 +53,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from . import arrays as A
 from .constants import (CAMERA_FPS, DETNET_FPS, KEYNET_FPS, NUM_CAMERAS,
@@ -423,7 +422,7 @@ def decode_flat_index(shape: Sequence[int], flat):
     Index spaces beyond int32 (> 2^31-config grids) are guarded: a
     narrow integer array input is promoted to int64 before the stride
     arithmetic, so ``flat // stride`` can never overflow.  For traced
-    jax inputs the promotion needs the caller's scoped ``enable_x64``
+    jax inputs the promotion needs the caller's scoped ``jax.enable_x64``
     context (which every engine here runs under) — without it the
     astype would silently stay 32-bit.
     """
@@ -736,7 +735,7 @@ def evaluate_grid(cuts: Optional[Iterable[int]] = None,
     full_shape = tuple(a.size for a in axis_arrays)
     n = int(np.prod(full_shape))
 
-    with enable_x64():
+    with jax.enable_x64(True):
         evalfn = _backend.cached_dense_eval(backend, S, full_shape,
                                             kernel_fields(S))
         out = evalfn(tuple(map(jnp.asarray, axis_arrays)),

@@ -20,9 +20,11 @@ class PallasGridBackend(B.EvalBackend):
     """Evaluation backend lowering the chunk contract onto
     :mod:`.kernel`'s fused ``pallas_call``.
 
-    ``interpret=None`` (default) auto-selects: interpreter mode on
-    non-TPU platforms (the CPU CI/parity configuration), compiled
-    Mosaic on TPU.  The multi-device ``pmap`` path is not supported —
+    The kernel runs in Pallas interpret mode on the CPU (the CI/parity
+    configuration) and nowhere else: on any other platform building a
+    step raises :class:`NotImplementedError` (see :func:`_interpret`)
+    instead of silently interpreting on the host or failing deep in the
+    Mosaic lowering.  The multi-device ``pmap`` path is not supported —
     shard across Pallas-capable devices by passing explicit
     single-device ``devices=`` lists per process instead.  Scenario
     sweeps (``scenarios=`` — the session ``lax.scan`` kernel of
@@ -36,21 +38,13 @@ class PallasGridBackend(B.EvalBackend):
     supports_pmap = False
     supports_scenarios = False
 
-    def __init__(self, interpret: bool | None = None):
-        self.interpret = interpret
-
-    def _interpret(self) -> bool:
-        if self.interpret is not None:
-            return self.interpret
-        return jax.local_devices()[0].platform != "tpu"
-
     def build_chunk_eval(self, spec):
-        return kernel.build_chunk_call(spec, interpret=self._interpret())
+        return kernel.build_chunk_call(spec, interpret=_interpret())
 
     def build_dense_eval(self, S, shape, fields):
         fields = tuple(fields)
         shape = tuple(shape)
-        interpret = self._interpret()
+        interpret = _interpret()
 
         @jax.jit
         def evalfn(axvals, flat):
@@ -58,6 +52,26 @@ class PallasGridBackend(B.EvalBackend):
                                           interpret=interpret)
 
         return evalfn
+
+
+def _interpret() -> bool:
+    """Interpret mode on the CPU; refuse every other platform.
+
+    The compiled Mosaic lowering does not exist yet, and interpreting
+    the kernel on the host of an accelerator would hide the device."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    raise NotImplementedError(
+        f"backend='pallas' cannot run on {platform!r}: the sweep_grid "
+        f"kernel has no compiled Mosaic lowering yet.  The TPU compiler "
+        f"refuses its per-block partial outputs ((n, 1) blocks of an "
+        f"(n, n_blocks) array are not (8, 128)-aligned, the pallas_call "
+        f"of kernels/sweep_grid/kernel.py build_chunk_call) and its "
+        f"float64 outputs (ROADMAP Speed items 2-3: the float32 device "
+        f"path, then the compiled lowering).  Use backend='xla' (the "
+        f"default) on accelerators; Pallas interpret mode runs only on "
+        f"the CPU.")
 
 
 B.register_backend(PallasGridBackend())

@@ -570,10 +570,30 @@ def worker_loop(spool: str, wid: Optional[str] = None,
 # ---------------------------------------------------------------------------
 
 
+def require_cpu_pool() -> None:
+    """Refuse a worker pool unless JAX's default backend is the CPU.
+
+    Pool children are pinned to one device by a host-platform flag that
+    means nothing to an accelerator: on a chip host every child would
+    reach for the chip the coordinator already holds.  There the
+    in-process path (and ``pmap`` over local devices) is the path."""
+    import jax
+
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"the sweep worker pool runs only on the CPU backend, not "
+            f"{platform!r}: one process owns a chip, and pool children "
+            f"pinned by --xla_force_host_platform_device_count would all "
+            f"contend for it.  Use workers=0 (in-process execution, "
+            f"pmap across local devices).")
+
+
 class WorkerPool:
     """Spawn and supervise ``n`` worker subprocesses over one spool.
 
-    Each child is pinned to a single JAX host device
+    CPU only (:func:`require_cpu_pool`).  Each child runs with
+    ``JAX_PLATFORMS=cpu`` pinned to a single JAX host device
     (``--xla_force_host_platform_device_count=1``) so its dispatch
     quantum matches the lease alignment, and logs to
     ``<spool>/workers/w<i>.log``.  :meth:`ensure` respawns dead
@@ -584,6 +604,7 @@ class WorkerPool:
 
     def __init__(self, spool: str, n: int, ttl_s: float = DEFAULT_TTL_S,
                  poll_s: float = 0.1, respawn: bool = True):
+        require_cpu_pool()
         self.spool = str(spool)
         self.n = int(n)
         self.ttl_s = float(ttl_s)
@@ -597,7 +618,7 @@ class WorkerPool:
             self._spawn(i)
 
     def _spawn(self, i: int) -> None:
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         flags = [f for f in env.get("XLA_FLAGS", "").split()
                  if not f.startswith("--xla_force_host_platform_device_count")]
         flags.append("--xla_force_host_platform_device_count=1")
@@ -677,6 +698,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--once", action="store_true",
                     help="exit when no lease is claimable (batch drain)")
     a = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     return worker_loop(a.spool, wid=a.wid, ttl=a.ttl, poll_s=a.poll,
                        once=a.once)
 

@@ -225,6 +225,22 @@ class TestDenseBackend:
         assert r1.argmin() == one.argmin()
 
 
+class TestPallasPlatform:
+    @pytest.mark.parametrize("platform", ["tpu", "gpu"])
+    def test_refuses_every_platform_but_the_cpu(self, monkeypatch,
+                                                platform):
+        # No compiled Mosaic lowering yet: off the CPU the step build
+        # fails clearly instead of interpreting on the host.
+        from repro.kernels.sweep_grid import PallasGridBackend
+
+        S, axis_vals, _ = sweep.build_axes(cuts=(0, 17))
+        shape = tuple(a.size for a in axis_vals)
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        with pytest.raises(NotImplementedError, match="Mosaic") as ei:
+            PallasGridBackend().build_dense_eval(S, shape, ("avg_power",))
+        assert "float64" in str(ei.value) and "(8, 128)" in str(ei.value)
+
+
 class TestPallasKernelOracle:
     def test_chunk_partials_match_xla_reference(self):
         """The fused pallas_call must reproduce every block partial of
@@ -232,7 +248,6 @@ class TestPallasKernelOracle:
         from repro.kernels import sweep_grid
 
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         S, axis_vals, _ = sweep.build_axes(sensor_nodes=("7nm", "16nm"),
                                            weight_mems=("sram", "mram"))
@@ -243,7 +258,7 @@ class TestPallasKernelOracle:
             fields=tuple(pareto.DEFAULT_OBJECTIVES), d=3, k=4,
             sign=(1.0, 1.0, 1.0), cons_static=(), hist_bins=0,
             survivor_cap=96, small_index=True)
-        with enable_x64():
+        with jax.enable_x64(True):
             axvals = tuple(map(jnp.asarray, axis_vals))
             filt = pareto.build_dominance_filter(
                 np.empty((0, 3)), 3, spec.filter_rows, spec.filter_bins)
@@ -284,9 +299,8 @@ class TestInt64Decode:
 
     def test_traced_decode_beyond_int32(self):
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
-        with enable_x64():
+        with jax.enable_x64(True):
             flat = jnp.asarray([2**33 + 12345, 10**10 - 1], jnp.int64)
             ours = [np.asarray(c)
                     for c in jax.jit(

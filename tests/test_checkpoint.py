@@ -19,8 +19,8 @@ import os
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.checkpoint import CheckpointManager
 
@@ -29,7 +29,7 @@ def _restore(mgr, step, like):
     """``restore`` places leaves as jnp arrays; keep 64-bit dtypes
     intact (the executor itself restores via ``restore_items``, which
     stays in numpy and never downcasts)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         return mgr.restore(step, like=like)
 
 

@@ -6,7 +6,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import optimize, sweep
 from repro.core.handtracking import build_detnet, build_keynet
@@ -65,7 +64,7 @@ class TestGradient:
 
     def test_raw_objective_fn_is_differentiable(self):
         f = optimize.objective_fn("avg_power", **CONFIG)
-        with enable_x64():
+        with jax.enable_x64(True):
             g = jax.grad(lambda s: f({"mipi_energy_scale": s}))(
                 jnp.asarray(1.0))
             assert np.isfinite(float(g))

@@ -24,7 +24,7 @@ import math
 import numpy as np
 import pytest
 
-from jax.experimental import enable_x64
+import jax
 
 from repro.core import pareto, partition, scenario as SC, stream, sweep
 from repro.core.constants import (DEFAULT_BATTERY, DEFAULT_THERMAL,
@@ -75,7 +75,7 @@ class TestClosedFormOracles:
         th = DEFAULT_THERMAL
         tau = th.r_th_k_per_w * th.c_th_j_per_k
         P, D = 0.15, 600.0
-        with enable_x64():
+        with jax.enable_x64(True):
             temp = th.ambient_c
             for _ in range(16):
                 temp = float(SC.thermal_step(temp, P, D / 16, th))
@@ -96,7 +96,7 @@ class TestClosedFormOracles:
     def test_battery_linear_drain_is_bitwise(self):
         """peukert == 1.0 -> exponent exactly 0.0 -> drain == power."""
         assert DEFAULT_BATTERY.peukert == 1.0
-        with enable_x64():
+        with jax.enable_x64(True):
             for p in (0.019, 0.37, 2.5):
                 assert float(SC.effective_drain_w(p, DEFAULT_BATTERY)) == p
 
@@ -146,7 +146,7 @@ class TestClosedFormOracles:
         # power is tiny but nonzero, so check the sentinel via a direct
         # zero-power finalize instead of a grid corner
         import jax.numpy as jnp
-        with enable_x64():
+        with jax.enable_x64(True):
             carry = SC._init_carry(sset)
             carry = (jnp.float64(60.0),) + carry[1:]
             out = SC._finalize(carry, jnp.float64(0.0), sset.battery)
@@ -401,7 +401,7 @@ class TestSessionProperties:
         no-op (the RC step is exact); channels agree to 1e-9."""
         ref = _small_grid(_single(dur))
         split = SC.ScenarioSet(traces=(SC.ScenarioTrace(
-            "const", (SC.Phase(frac * dur), SC.Phase((1 - frac) * dur)),)),
+            "const", (SC.Phase(frac * dur), SC.Phase((1 - frac) * dur))),),
             throttle=False)
         r2 = _small_grid(split)
         for f in sweep.SCENARIO_FIELDS:

@@ -587,3 +587,22 @@ class TestCLI:
         assert lines[0]["fraction_complete"] == 1.0
         assert "argmin" in lines[0]
         assert lines[-1]["health"]["counters"]["completed"] == 1
+
+    def test_module_entry_point_fails_when_a_request_fails(self, tmp_path):
+        """A failed request makes `python -m repro.service` exit 1."""
+        reqfile = tmp_path / "reqs.jsonl"
+        reqfile.write_text(json.dumps(
+            _request(objectives=("no_such_channel",)).to_json()) + "\n")
+        env = dict(os.environ)
+        env["PYTHONPATH"] = os.pathsep.join(
+            [os.path.join(os.path.dirname(__file__), "..", "src")]
+            + env.get("PYTHONPATH", "").split(os.pathsep))
+        out = subprocess.run(
+            [sys.executable, "-m", "repro.service",
+             "--requests", str(reqfile), "--timeout-s", "600"],
+            env=env, capture_output=True, text=True, timeout=600)
+        assert out.returncode == 1, out.stderr[-2000:]
+        lines = [json.loads(l) for l in out.stdout.strip().splitlines()]
+        assert lines[0]["state"] == "failed"
+        assert "no_such_channel" in lines[0]["error"]
+        assert lines[-1]["health"]["counters"]["failed"] == 1

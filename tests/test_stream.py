@@ -99,6 +99,32 @@ class TestStreamDenseParity:
 
 
 class TestStreamMechanics:
+    def test_explicit_devices_pin_the_probe(self, monkeypatch):
+        # An explicit devices= list must pin every device op of the run
+        # (a CPU reference on a chip host evaluates nothing on the
+        # chip), not only the chunk steps.
+        import jax
+
+        from repro.core import backend as B
+
+        dev = jax.devices()[-1]
+        seen = []
+        real = B.cached_dense_eval
+
+        def spy(*a, **kw):
+            fn = real(*a, **kw)
+
+            def run(axvals, flat):
+                seen.append(flat.devices())
+                return fn(axvals, flat)
+            return run
+
+        monkeypatch.setattr(B, "cached_dense_eval", spy)
+        with jax.default_device(jax.devices()[0]):
+            stream.stream_grid(cuts=(0, 17), detnet_fps=(5.0, 30.0),
+                               devices=[dev])
+        assert seen and all(s == {dev} for s in seen)
+
     def test_histograms_match_dense(self, dense):
         res = stream.stream_grid(**REFERENCE_GRID, chunk_size=777,
                                  hist_bins=16)

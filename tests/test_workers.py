@@ -221,6 +221,20 @@ class TestPooledService:
         finally:
             svc.close()
 
+    @pytest.mark.parametrize("make", ["pool", "service"])
+    def test_refused_off_the_cpu_backend(self, tmp_path, monkeypatch,
+                                         make):
+        # Pool children are pinned by a host-platform flag only: on a
+        # chip host they would all contend for the parent's chip.
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="only on the CPU backend"):
+            if make == "pool":
+                wk.WorkerPool(str(tmp_path / "spool"), 2)
+            else:
+                SweepService(workers=2, spool_dir=str(tmp_path / "spool"))
+        assert not (tmp_path / "spool").exists()   # nothing spawned
+
     def test_deadline_requests_bypass_the_pool(self, tmp_path, solo):
         svc = SweepService(capacity=4, snapshot_every_s=0.0, workers=1,
                            spool_dir=str(tmp_path / "spool"))

@@ -237,7 +237,8 @@ def chunk_partials(spec: ChunkSpec, F, flat, ingrid, aux) -> dict:
         Fsg = Fm[:d]
     else:
         Fsg = jnp.where(valid[:d], F[:d] * sign[:, None], jnp.inf)
-    keep = P.dominance_filter_mask(aux["filter"], Fsg, xp=jnp)
+    with jax.named_scope("dominance_filter"):
+        keep = P.dominance_filter_mask(aux["filter"], Fsg, xp=jnp)
 
     lane_pad = spec.padded - spec.chunk
 
@@ -385,7 +386,10 @@ class XlaBackend(EvalBackend):
 
         @jax.jit
         def evalfn(axvals, flat):
-            out = kernel(*decode_gather(shape, axvals, flat))
+            with jax.named_scope("decode_gather"):
+                args = decode_gather(shape, axvals, flat)
+            with jax.named_scope("eq1_11"):
+                out = kernel(*args)
             return {f: out[f] for f in fields}
 
         return evalfn
@@ -394,19 +398,23 @@ class XlaBackend(EvalBackend):
         kernel = SW.vmapped_kernel(spec.S)
 
         def evalfn(axvals, aux, start):
-            flat = start + jnp.arange(spec.chunk, dtype=jnp.int64)
-            ingrid = flat < spec.n_total
-            # int32 decode arithmetic when the flat index space fits —
-            # int64 div/mod is measurably slower on CPU.
-            fdec = flat.astype(jnp.int32) if spec.small_index else flat
-            out = kernel(*decode_gather(spec.shape, axvals, fdec))
-            F = jnp.stack([out[f] for f in spec.fields])
-            # Without the barrier XLA fuses the (expensive) kernel body
-            # into every reduction that consumes F, re-evaluating it
-            # several times per chunk; the barrier forces one
-            # materialization.
-            F = jax.lax.optimization_barrier(F)
-            return chunk_partials(spec, F, flat, ingrid, aux)
+            with jax.named_scope("decode_gather"):
+                flat = start + jnp.arange(spec.chunk, dtype=jnp.int64)
+                ingrid = flat < spec.n_total
+                # int32 decode arithmetic when the flat index space
+                # fits — int64 div/mod is measurably slower on CPU.
+                fdec = flat.astype(jnp.int32) if spec.small_index else flat
+                args = decode_gather(spec.shape, axvals, fdec)
+            with jax.named_scope("eq1_11"):
+                out = kernel(*args)
+                F = jnp.stack([out[f] for f in spec.fields])
+                # Without the barrier XLA fuses the (expensive) kernel
+                # body into every reduction that consumes F,
+                # re-evaluating it several times per chunk; the barrier
+                # forces one materialization.
+                F = jax.lax.optimization_barrier(F)
+            with jax.named_scope("chunk_partials"):
+                return chunk_partials(spec, F, flat, ingrid, aux)
 
         return evalfn
 
@@ -444,7 +452,8 @@ def build_step(spec: ChunkSpec, backend: str | None = None,
 
     def one(carry, axvals, aux, start):
         partials = evalfn(axvals, aux, start)
-        return fold_chunk(spec, carry, partials, aux, start)
+        with jax.named_scope("fold_chunk"):
+            return fold_chunk(spec, carry, partials, aux, start)
 
     if scan_chunks > 1:
         def step(carry, axvals, aux, start):

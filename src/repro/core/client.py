@@ -48,6 +48,7 @@ from typing import Callable, Optional, Sequence, Union
 
 from ..runtime.admission import BackpressureError
 from ..runtime.transport import AuthenticationError  # re-export
+from ..runtime import spans as SP
 from ..runtime import transport as T
 from . import service as CS
 from . import stream as ST
@@ -156,7 +157,8 @@ class RemoteTicket:
                     continue
                 raise
             self.state = final.get("state", self.state)
-            res = ST.result_from_json(final["result"])
+            with SP.span("repro.wire.decode", ticket=self.id):
+                res = ST.result_from_json(final["result"])
             # Wire bytes this wait cost (submit + watch stream + final
             # frame) — the delta-streaming savings show up right here.
             res.stats["watch_wire_bytes"] = float(

@@ -77,6 +77,7 @@ import numpy as np
 
 from ..runtime.admission import AdmissionQueue, BackpressureError, Deadline
 from ..runtime.fault_tolerance import RetryPolicy
+from ..runtime import spans as SP
 from . import backend as B
 from . import pareto as P
 from . import stream as ST
@@ -306,6 +307,8 @@ class Ticket:
         self.client_id = client_id
         self.tenant = request.tenant
         self.deadline = Deadline.after(request.deadline_s)
+        # Admission stamp: the queue wait of the repro.service.start span.
+        self.admitted_at = time.monotonic()
         self.state = QUEUED
         self.progress = 0.0
         self.signature: Optional[str] = None
@@ -566,6 +569,13 @@ class SweepService:
         lets :class:`repro.core.client.SweepClient` blindly retry a
         submit whose response was lost to a dropped connection or a
         server crash."""
+        with SP.span("repro.service.admit",
+                     **({} if client_id is None
+                        else {"client_id": client_id})):
+            return self._admit(request, client_id)
+
+    def _admit(self, request: SweepRequest,
+               client_id: Optional[str]) -> Ticket:
         if self._shutdown.is_set():
             raise ServiceClosedError("service is shut down")
         req = request.normalized()
@@ -677,25 +687,27 @@ class SweepService:
         # persist the freshest state.
         with self._journal_lock:
             journal_state = state or t.state
-            try:
-                payload = {"id": t.id, "seq": t.seq,
-                           "state": journal_state,
-                           "signature": t.signature,
-                           "client_id": t.client_id,
-                           "request": t.request.to_json(),
-                           "error": (str(t._error)
-                                     if t._error is not None else None)}
-                if journal_state == DONE and t._result is not None:
-                    payload["result"] = ST.result_to_json(t._result)
-            except TypeError:
-                return      # volatile request (live model objects)
-            path = os.path.join(self._requests_dir, f"{t.id}.json")
-            tmp = path + ".tmp"
-            with open(tmp, "w") as fh:
-                json.dump(payload, fh)
-                fh.flush()
-                os.fsync(fh.fileno())
-            os.replace(tmp, path)
+            with SP.span("repro.service.journal", ticket=t.id,
+                         state=journal_state):
+                try:
+                    payload = {"id": t.id, "seq": t.seq,
+                               "state": journal_state,
+                               "signature": t.signature,
+                               "client_id": t.client_id,
+                               "request": t.request.to_json(),
+                               "error": (str(t._error)
+                                         if t._error is not None else None)}
+                    if journal_state == DONE and t._result is not None:
+                        payload["result"] = ST.result_to_json(t._result)
+                except TypeError:
+                    return      # volatile request (live model objects)
+                path = os.path.join(self._requests_dir, f"{t.id}.json")
+                tmp = path + ".tmp"
+                with open(tmp, "w") as fh:
+                    json.dump(payload, fh)
+                    fh.flush()
+                    os.fsync(fh.fileno())
+                os.replace(tmp, path)
 
     def _recover(self) -> None:
         """Re-admit every journaled request left queued or running by a
@@ -795,18 +807,19 @@ class SweepService:
         """Resolve (or fetch) the content-signature-keyed plan — the
         LRU that keeps :func:`repro.core.backend.cached_step` hitting
         across requests for byte-identical jobs."""
-        plan = ST.plan_stream(**plan_kwargs(req))
-        with self._lock:
-            cached = self._plans.get(plan.signature)
-            if cached is not None:
-                self.counters["plan_hits"] += 1
-                self._plans.move_to_end(plan.signature)
-                return cached
-            self.counters["plan_misses"] += 1
-            self._plans[plan.signature] = plan
-            while len(self._plans) > self._plan_cache_size:
-                self._plans.popitem(last=False)
-        return plan
+        with SP.span("repro.service.plan"):
+            plan = ST.plan_stream(**plan_kwargs(req))
+            with self._lock:
+                cached = self._plans.get(plan.signature)
+                if cached is not None:
+                    self.counters["plan_hits"] += 1
+                    self._plans.move_to_end(plan.signature)
+                    return cached
+                self.counters["plan_misses"] += 1
+                self._plans[plan.signature] = plan
+                while len(self._plans) > self._plan_cache_size:
+                    self._plans.popitem(last=False)
+            return plan
 
     # -- internals: execution -------------------------------------------
 
@@ -877,9 +890,11 @@ class SweepService:
                 t._update_snapshot(snap)
 
         for t in members:
-            t.state = RUNNING
-            t.signature = plan.signature
-            self._journal(t)
+            with SP.span("repro.service.start", ticket=t.id,
+                         queued_s=time.monotonic() - t.admitted_at):
+                t.state = RUNNING
+                t.signature = plan.signature
+                self._journal(t)
         with self._lock:
             self.counters["executions"] += 1
             if len(members) > 1:
@@ -895,25 +910,28 @@ class SweepService:
                 fused.to_json()
             except TypeError:
                 use_pool = False    # volatile request: run in-process
+        ids = ",".join(t.id for t in members)
         try:
-            if use_pool:
-                res = self._execute_pooled(fused, plan, should_stop,
-                                           cause, on_progress,
-                                           on_snapshot)
-            else:
-                res = ST.stream_grid(
-                    plan=plan, prefetch=self._prefetch,
-                    checkpoint_dir=(self._ckpt_dir(plan.signature)
-                                    if self.spool_dir is not None
-                                    else None),
-                    checkpoint_every_s=self._ckpt_every_s,
-                    checkpoint_every_steps=self._ckpt_every_steps,
-                    checkpoint_keep=self._ckpt_keep,
-                    retry_policy=self._retry_policy,
-                    fault_injector=self._fault_injector,
-                    should_stop=should_stop, on_progress=on_progress,
-                    on_snapshot=on_snapshot,
-                    snapshot_every_s=self._snapshot_every_s)
+            with SP.span("repro.service.execute", ticket=ids):
+                if use_pool:
+                    res = self._execute_pooled(fused, plan, should_stop,
+                                               cause, on_progress,
+                                               on_snapshot)
+                else:
+                    res = ST.stream_grid(
+                        plan=plan, prefetch=self._prefetch,
+                        checkpoint_dir=(self._ckpt_dir(plan.signature)
+                                        if self.spool_dir is not None
+                                        else None),
+                        checkpoint_every_s=self._ckpt_every_s,
+                        checkpoint_every_steps=self._ckpt_every_steps,
+                        checkpoint_keep=self._ckpt_keep,
+                        retry_policy=self._retry_policy,
+                        fault_injector=self._fault_injector,
+                        should_stop=should_stop, on_progress=on_progress,
+                        on_snapshot=on_snapshot,
+                        snapshot_every_s=self._snapshot_every_s,
+                        ticket=ids)
         except _PoolPreempted:
             # Shutdown mid-pooled-run: leave the tickets unfinished —
             # close() fails them with journal state RUNNING, and a new

@@ -107,6 +107,7 @@ from ..checkpoint import CheckpointManager
 from ..runtime.elastic import drop_worker
 from ..runtime.fault_injection import DeviceLostError, TransientDeviceError
 from ..runtime.fault_tolerance import RetryPolicy, StragglerDetector
+from ..runtime import spans as SP
 from . import arrays as A
 from . import backend as B
 from . import pareto as P
@@ -847,7 +848,8 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
                 should_stop=None,
                 on_progress=None,
                 on_snapshot=None,
-                snapshot_every_s: float = 0.5) -> StreamResult:
+                snapshot_every_s: float = 0.5,
+                ticket: Optional[str] = None) -> StreamResult:
     """Stream Eqs. 1-11 over an arbitrarily large cartesian grid.
 
     Same axes (and ``models=`` workload batch) as
@@ -952,16 +954,28 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
     streams to subscribed clients; snapshots obey the same durability
     ordering (a step with a checkpoint due emits its snapshot only
     after the checkpoint is on disk).
+
+    The executor's phases open profiler spans (:mod:`repro.runtime.
+    spans`): ``repro.stream.plan`` (the spec resolution when no
+    ``plan`` is given), ``repro.stream.grid`` around the rest — with
+    ``ticket`` as its metadata when the sweep service is the caller —
+    and inside it ``.probe``, ``.dispatch``, ``.fetch``, ``.merge``,
+    ``.fallback`` and ``.checkpoint``.  The spans of ``.grid``,
+    ``.fetch``, ``.merge``, ``.dispatch``, ``.fallback`` and
+    ``.checkpoint`` are the clocks of ``stats``' ``total_s``,
+    ``device_wait_s``, ``host_merge_s``, ``dispatch_s``, ``fallback_s``
+    and ``checkpoint_write_s``.
     """
     if plan is None:
-        plan = plan_stream(
-            cuts, agg_nodes, sensor_nodes, weight_mems, detnet_fps,
-            keynet_fps, num_cameras, mipi_energy_scale, camera_fps,
-            detnet, keynet, model, models, scenarios,
-            chunk_size=chunk_size, top_k=top_k, objectives=objectives,
-            maximize=maximize, track=track, constraints=constraints,
-            hist_bins=hist_bins, hist_ranges=hist_ranges, devices=devices,
-            backend=backend, scan_chunks=scan_chunks)
+        with SP.span("repro.stream.plan"):
+            plan = plan_stream(
+                cuts, agg_nodes, sensor_nodes, weight_mems, detnet_fps,
+                keynet_fps, num_cameras, mipi_energy_scale, camera_fps,
+                detnet, keynet, model, models, scenarios,
+                chunk_size=chunk_size, top_k=top_k, objectives=objectives,
+                maximize=maximize, track=track, constraints=constraints,
+                hist_bins=hist_bins, hist_ranges=hist_ranges,
+                devices=devices, backend=backend, scan_chunks=scan_chunks)
     S = plan.S
     axis_vals = list(plan.axis_vals)
     axes = plan.axes
@@ -1007,7 +1021,9 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
     n_steps = math.ceil(span / per_step)
     prefetch = max(0, int(prefetch))
 
-    t0 = time.perf_counter()
+    # The executor's clocks, each the duration of its span (SP.timed).
+    clk = {"total_s": 0.0, "device_wait_s": 0.0, "host_merge_s": 0.0,
+           "dispatch_s": 0.0, "fallback_s": 0.0}
     policy = retry_policy if retry_policy is not None else RetryPolicy()
     counters = {
         "retries": 0.0, "restarts": 0.0, "resumed_from_step": 0.0,
@@ -1022,10 +1038,13 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
         return (jax.default_device(dev_list[0]) if plan.explicit_devices
                 else contextlib.nullcontext())
 
-    with jax.enable_x64(True), on_devices():
-        seed_signed, hist_edges, axis_valid = _probe(
-            S, axis_vals, full_shape, n_total, objectives, sign, cons,
-            hist_bins, hist_ranges)
+    with SP.timed(clk, "total_s", "repro.stream.grid",
+                  **({} if ticket is None else {"ticket": ticket})) as t0, \
+            jax.enable_x64(True), on_devices():
+        with SP.span("repro.stream.probe"):
+            seed_signed, hist_edges, axis_valid = _probe(
+                S, axis_vals, full_shape, n_total, objectives, sign, cons,
+                hist_bins, hist_ranges)
 
         # The consistent snapshot all recovery pivots on: the merged
         # (device-count-independent) host carry, the exact running
@@ -1051,16 +1070,16 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
             _resume_into(mgr, signature, state, counters, chunk)
 
         def write_checkpoint():
-            tw = time.perf_counter()
-            mgr.save(int(state["base"]),
-                     {"carry": state["carry"],
-                      "front_values": state["front_vals"],
-                      "front_indices": state["front_idx"]},
-                     metadata={"signature": signature,
-                               "next_flat": int(state["base"]),
-                               "counters": dict(counters),
-                               "format": B.CARRY_VERSION})
-            counters["checkpoint_write_s"] += time.perf_counter() - tw
+            with SP.timed(counters, "checkpoint_write_s",
+                          "repro.stream.checkpoint"):
+                mgr.save(int(state["base"]),
+                         {"carry": state["carry"],
+                          "front_values": state["front_vals"],
+                          "front_indices": state["front_idx"]},
+                         metadata={"signature": signature,
+                                   "next_flat": int(state["base"]),
+                                   "counters": dict(counters),
+                                   "format": B.CARRY_VERSION})
             counters["checkpoints_written"] += 1.0
 
         # Pre-cull the probe seed toward its near-front subset once: the
@@ -1074,9 +1093,6 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
             seed_signed = seed_signed[P.dominance_filter_mask(
                 f0, np.ascontiguousarray(seed_signed.T), xp=np)]
         t_first = None
-        t_wait = 0.0
-        t_host = 0.0
-        t_dispatch = 0.0
         n_fallback = 0
         detector = StragglerDetector(policy.straggler_factor,
                                      policy.straggler_window)
@@ -1097,8 +1113,7 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
             # every remaining chunk, then advance the snapshot to
             # completion.  Raises on device loss / exhausted retries;
             # the control loop below decides replan vs restart.
-            nonlocal t_first, t_wait, t_host, t_dispatch, n_fallback
-            nonlocal dispatched_flat
+            nonlocal t_first, n_fallback, dispatched_flat
             base = state["base"]
             if base >= stop0:       # resumed-from-complete: nothing left
                 return
@@ -1171,22 +1186,23 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
                 # pre-cull keeps the exact dominance passes to a few
                 # hundred rows.
                 nonlocal front_vals, front_idx, buf_vals, buf_idx, buf_n
-                if buf_n:
-                    cat_v = np.concatenate(buf_vals)
-                    cat_i = np.concatenate(buf_idx)
-                    cat_sg = cat_v * sign
-                    base_sg = np.concatenate([front_vals * sign, cat_sg,
-                                              seed_signed])
-                    f = P.build_dominance_filter(base_sg, d, _FILTER_ROWS,
-                                                 _FILTER_BINS)
-                    keep = P.dominance_filter_mask(
-                        f, np.ascontiguousarray(cat_sg.T), xp=np)
-                    front_vals, front_idx = _merge_into_front(
-                        front_vals, front_idx, cat_v[keep], cat_i[keep],
-                        sign)
-                    buf_vals, buf_idx, buf_n = [], [], 0
-                if not final:
-                    rebuild_filter()
+                with SP.span("repro.stream.merge"):
+                    if buf_n:
+                        cat_v = np.concatenate(buf_vals)
+                        cat_i = np.concatenate(buf_idx)
+                        cat_sg = cat_v * sign
+                        base_sg = np.concatenate([front_vals * sign, cat_sg,
+                                                  seed_signed])
+                        f = P.build_dominance_filter(base_sg, d, _FILTER_ROWS,
+                                                     _FILTER_BINS)
+                        keep = P.dominance_filter_mask(
+                            f, np.ascontiguousarray(cat_sg.T), xp=np)
+                        front_vals, front_idx = _merge_into_front(
+                            front_vals, front_idx, cat_v[keep], cat_i[keep],
+                            sign)
+                        buf_vals, buf_idx, buf_n = [], [], 0
+                    if not final:
+                        rebuild_filter()
 
             def host_chunk_survivors(dstart, vlen):
                 # Survivor-capacity overflow (warmup-only in practice):
@@ -1241,38 +1257,42 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
                 # driver re-emits it after the checkpoint is durable,
                 # so a watcher can never observe progress that a kill
                 # right after the frame would roll back past.
-                nonlocal buf_n, t_wait, t_host, t_first, n_fallback
+                nonlocal buf_n, t_first, n_fallback
                 start, surv = item
-                tw = time.perf_counter()
-                flat_s, val_s, cnt_s = (np.asarray(x) for x in surv)
-                t_wait += time.perf_counter() - tw
-                th = time.perf_counter()
-                flat_s = flat_s.reshape(n_sub, -1)
-                val_s = val_s.reshape(n_sub, -1, d)
-                cnt_s = cnt_s.reshape(n_sub)
-                for j in range(n_sub):
-                    dstart = start + chunk * j
-                    vlen = min(chunk, n_total - dstart)
-                    if vlen <= 0:
-                        break
-                    cnt = int(cnt_s[j])
-                    if cnt > cap:
-                        n_fallback += 1
-                        fl, vv = host_chunk_survivors(dstart, vlen)
-                    else:
-                        fl = flat_s[j][:cnt]
-                        vv = val_s[j][:cnt]
-                    if len(fl):
-                        buf_idx.append(np.asarray(fl, np.int64))
-                        buf_vals.append(np.asarray(vv, np.float64))
-                        buf_n += len(fl)
-                if buf_n >= _MERGE_EVERY:
-                    merge()
-                if not defer_snap:
-                    maybe_snapshot(min(start + per_step, stop0))
-                if t_first is None:
-                    t_first = time.perf_counter() - t0
-                t_host += time.perf_counter() - th
+                step = (start - base) // per_step
+                with SP.timed(clk, "device_wait_s", "repro.stream.fetch",
+                              step=step):
+                    flat_s, val_s, cnt_s = (np.asarray(x) for x in surv)
+                with SP.timed(clk, "host_merge_s", "repro.stream.merge",
+                              step=step):
+                    flat_s = flat_s.reshape(n_sub, -1)
+                    val_s = val_s.reshape(n_sub, -1, d)
+                    cnt_s = cnt_s.reshape(n_sub)
+                    for j in range(n_sub):
+                        dstart = start + chunk * j
+                        vlen = min(chunk, n_total - dstart)
+                        if vlen <= 0:
+                            break
+                        cnt = int(cnt_s[j])
+                        if cnt > cap:
+                            n_fallback += 1
+                            with SP.timed(clk, "fallback_s",
+                                          "repro.stream.fallback",
+                                          chunk=dstart // chunk):
+                                fl, vv = host_chunk_survivors(dstart, vlen)
+                        else:
+                            fl = flat_s[j][:cnt]
+                            vv = val_s[j][:cnt]
+                        if len(fl):
+                            buf_idx.append(np.asarray(fl, np.int64))
+                            buf_vals.append(np.asarray(vv, np.float64))
+                            buf_n += len(fl)
+                    if buf_n >= _MERGE_EVERY:
+                        merge()
+                    if not defer_snap:
+                        maybe_snapshot(min(start + per_step, stop0))
+                    if t_first is None:
+                        t_first = time.perf_counter() - t0
 
             def make_starts(si):
                 start = base + si * per_step
@@ -1297,7 +1317,7 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
                 # the dispatch in place is safe; anything raised by
                 # run() itself invalidates the carry and propagates to
                 # the restart loop instead.
-                nonlocal t_dispatch, dispatched_flat
+                nonlocal dispatched_flat
                 start = base + si * per_step
                 dispatched_flat = max(dispatched_flat,
                                       min(start + per_step, stop0))
@@ -1315,9 +1335,9 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
                             time.sleep(backoff)
                             backoff = min(backoff * 2.0,
                                           policy.backoff_max_s)
-                td = time.perf_counter()
-                c, surv = run(c, axvals_j, aux, make_starts(si))
-                t_dispatch += time.perf_counter() - td
+                with SP.timed(clk, "dispatch_s", "repro.stream.dispatch",
+                              step=si):
+                    c, surv = run(c, axvals_j, aux, make_starts(si))
                 dur = time.perf_counter() - tstep
                 if detector.record(dur):
                     counters["stragglers"] += 1.0
@@ -1565,7 +1585,7 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
         # halt, resume == continue from the stop point).
         if mgr is not None and mgr.latest_step() != state["base"]:
             write_checkpoint()
-    total_s = time.perf_counter() - t0
+    total_s = clk["total_s"]
 
     # Deliverables come straight off the committed snapshot — the same
     # arrays a checkpoint would persist, so a resumed run and an
@@ -1599,8 +1619,8 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
         # merges/buffering; device_wait_s is time blocked fetching chunk
         # survivors (≈ un-hidden device compute).  prefetch > 0 shrinks
         # device_wait_s toward the critical path.
-        "host_merge_s": t_host,
-        "device_wait_s": t_wait,
+        "host_merge_s": clk["host_merge_s"],
+        "device_wait_s": clk["device_wait_s"],
         # Dispatch accounting: time spent inside step invocation.  On
         # async accelerator backends this isolates the per-step launch
         # overhead scan fusion amortizes (K chunks per dispatch); XLA
@@ -1608,11 +1628,14 @@ def stream_grid(cuts: Optional[Iterable[int]] = None,
         # device compute — the dispatch *count* (n_chunks) is the
         # backend-independent signal, falling K-fold under scan_chunks.
         # A cold step's first call additionally pays trace + compile.
-        "dispatch_s": t_dispatch,
+        "dispatch_s": clk["dispatch_s"],
         "steps_per_s": n_steps / total_s if total_s else float("inf"),
         "scan_chunks": float(scan),
         "prefetch": float(prefetch),
         "fallback_chunks": float(n_fallback),
+        # Of host_merge_s: the dense re-derivation of the chunks that
+        # overflowed the survivor buffer.
+        "fallback_s": clk["fallback_s"],
         # Resilience accounting (see the stream_grid docstring):
         # in-place retries, pipeline restarts, the chunk ordinal a
         # resume started from, checkpoint count/time, chunk ranges

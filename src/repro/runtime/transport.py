@@ -477,6 +477,7 @@ class SweepServer:
         progress-snapshot) frames until it finishes, then the final
         result frame.  Runs on the connection's reader thread."""
         from ..core import stream as ST
+        from . import spans as SP
         deadline = (None if timeout is None
                     else time.monotonic() + float(timeout))
         prev_snap = None
@@ -518,8 +519,9 @@ class SweepServer:
             kind = _error_kind(t._error)
             send({**out, "error": kind, "message": str(t._error)})
             return
-        out["result"] = ST.result_to_json(t._result)
-        send(out)
+        with SP.span("repro.wire.encode", ticket=t.id):
+            out["result"] = ST.result_to_json(t._result)
+            send(out)
 
 
 def _error_kind(e: BaseException) -> str:

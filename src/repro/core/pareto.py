@@ -211,6 +211,22 @@ def build_dominance_filter(front_signed: np.ndarray, d: int,
     return state
 
 
+def edge_rank(edges, x, xp=np):
+    """Number of the sorted ``edges`` that are ``<= x``, per lane of ``x``:
+    ``searchsorted(edges, x, side="right")`` for every non-NaN ``x``.
+
+    On the host (``xp`` is ``numpy``) that is a C binary search, the fast
+    form over large arrays.  On the device it is a dense count: one
+    fused compare-and-sum pass over ``(edges, n)`` with no loop and no
+    gather, where ``jnp.searchsorted``'s default ``scan`` is a ``while``
+    loop of per-lane gathers from the edge vector that runs close to
+    serially on a TPU (and twice over on float64's emulated halves).
+    """
+    if xp is np:
+        return np.searchsorted(edges, x, side="right")
+    return (edges[:, None] <= x[None, :]).sum(axis=0, dtype=xp.int32)
+
+
 def dominance_filter_mask(state: Mapping, Osg, xp=np):
     """Rows of signed ``(d, n)`` channel block ``Osg`` the filter cannot
     prove dominated (finite rows only — masked/infeasible lanes are
@@ -219,7 +235,11 @@ def dominance_filter_mask(state: Mapping, Osg, xp=np):
     ``xp`` selects the array namespace: ``numpy`` for the streaming
     executor's host fallback path, ``jax.numpy`` when traced inside its
     compiled chunk step — the two evaluations are the same expression, so
-    the device pre-filter and its host mirror cannot drift.  Discarding
+    the device pre-filter and its host mirror cannot drift.  Only the
+    prefix-min table's bin takes a form per namespace (:func:`edge_rank`:
+    the device counts edges, the host binary-searches them), and both
+    give the same integer; ``tests/test_pareto_filter.py`` proves the
+    bins and the whole mask equal lane for lane.  Discarding
     is exact (both filter conditions are sufficient for domination);
     survivors still go through :func:`merge_fronts`.
     """
@@ -249,7 +269,7 @@ def dominance_filter_mask(state: Mapping, Osg, xp=np):
         for c in range(1, d):
             # Strictly-lower bin: a member binned below edges[c-1][b+1]
             # has a value < edges[c-1][b+1] <= p, hence strictly smaller.
-            b = xp.searchsorted(edges[c - 1], Osg[c], side="right") - 2
+            b = edge_rank(edges[c - 1], Osg[c], xp) - 2
             ok = (b >= 0) if ok is None else (ok & (b >= 0))
             idxs.append(xp.clip(b, 0, table.shape[0] - 1))
         dom = dom | (ok & (table[tuple(idxs)] <= Osg[0]))

@@ -11,6 +11,7 @@ TPU library.
 
 import importlib.util
 import os
+import re
 from pathlib import Path
 
 import numpy as np
@@ -48,7 +49,7 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
+@pytest.fixture(scope="module")
 def no_persistent_cache():
     # A compile for a described chip is written to the persistent cache
     # but cannot be read back without one; keep the cache out of it.
@@ -90,19 +91,31 @@ def _shapes(tree, sharding):
                                        sharding=sharding), tree)
 
 
-def test_study_chunk_step_compiles_for_one_v5e(study_step, one_chip,
-                                               no_persistent_cache):
+@pytest.fixture(scope="module")
+def compiled_study_step(study_step, one_chip, no_persistent_cache):
     spec, scan = study_step["spec"], study_step["scan"]
     assert spec.chunk == stream.DEFAULT_CHUNK == 1 << 17
     assert spec.n_total == 10_009_600 and spec.cons_static
     with jax.enable_x64(True):
         step = B.build_step(spec, "xla", scan, 1)
-        compiled = step.lower(*_shapes(study_step["args"],
-                                       one_chip)).compile()
-    mem = compiled.memory_analysis()
+        return step.lower(*_shapes(study_step["args"], one_chip)).compile()
+
+
+def test_study_chunk_step_compiles_for_one_v5e(compiled_study_step):
+    mem = compiled_study_step.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < 16e9              # one v5e holds 16 GB of HBM
+
+
+def test_study_chunk_step_dominance_filter_has_no_loop(compiled_study_step):
+    # The prefix-min table's bin is a dense count on the device, not a
+    # search loop of per-lane gathers (pareto.edge_rank).
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = .*?\s([a-z][\w-]*)\("
+                     r".*op_name=\"([^\"]*)\"",
+                     compiled_study_step.as_text(), re.M)
+    scoped = [op for op, name in ops if "/dominance_filter/" in name]
+    assert scoped and "while" not in scoped
 
 
 @pytest.mark.xfail(strict=True, raises=ValueError, reason=(

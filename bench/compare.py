@@ -44,21 +44,28 @@ def _rel(a, b):
 
 def _front_gaps(P, Rf):
     """(shortfall, excess) between a program front ``P`` and the
-    reference front ``Rf``, both as reference values ``(n, d)``."""
+    reference front ``Rf``, both as reference values ``(n, d)``.  A
+    channel can be 0 (no on-sensor work, no uTSV): gaps are relative to
+    a floor of 1e-300 there, as in :func:`_rel`, so a tie at 0 reads 0
+    and a gap over 0 reads far over any limit, where 0/0 would read NaN
+    and drop out of the maxima."""
     if P.shape[0] == 0 or Rf.shape[0] == 0:
         return (np.inf if Rf.shape[0] else 0.0,
                 np.inf if P.shape[0] else 0.0)
     short, excess = 0.0, 0.0
-    for lo in range(0, Rf.shape[0], 256):
-        r = Rf[lo:lo + 256]
-        worse = np.maximum(0.0, (P[None] - r[:, None]) / np.abs(r[:, None]))
-        short = max(short, float(worse.max(-1).min(-1).max()))
-    for lo in range(0, P.shape[0], 256):
-        p = P[lo:lo + 256]
-        beat = (p[:, None] - Rf[None]) / np.abs(p[:, None])
-        dom = (beat >= 0).all(-1) & (beat > 0).any(-1)
-        margin = np.where(dom, beat.min(-1), 0.0)
-        excess = max(excess, float(margin.max()))
+    with np.errstate(over="ignore"):
+        for lo in range(0, Rf.shape[0], 256):
+            r = Rf[lo:lo + 256]
+            worse = np.maximum(0.0, (P[None] - r[:, None])
+                               / np.maximum(np.abs(r[:, None]), 1e-300))
+            short = max(short, float(worse.max(-1).min(-1).max()))
+        for lo in range(0, P.shape[0], 256):
+            p = P[lo:lo + 256]
+            beat = (p[:, None] - Rf[None]) / np.maximum(
+                np.abs(p[:, None]), 1e-300)
+            dom = (beat >= 0).all(-1) & (beat > 0).any(-1)
+            margin = np.where(dom, beat.min(-1), 0.0)
+            excess = max(excess, float(margin.max()))
     return short, excess
 
 

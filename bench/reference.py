@@ -45,6 +45,12 @@ NODES = {
     "7nm": (0.109e-12, 700e6,
             (0.50e-12, 0.65e-12, 1.2986e-3 / MIB, 0.3420e-3 / MIB), None),
 }
+#: The channels :func:`channels` gives, one per output field of the
+#: engine's kernel: total power, its seven groups, MIPI link traffic,
+#: on-sensor compute and result latency.
+CHANNELS = ("avg_power", "camera", "utsv", "mipi", "sensor_compute",
+            "sensor_memory", "agg_compute", "agg_memory",
+            "mipi_bytes_per_s", "sensor_macs_per_s", "latency")
 AXES = ("cut", "agg_nodes", "sensor_nodes", "weight_mems", "detnet_fps",
         "keynet_fps", "num_cameras", "mipi_energy_scale", "camera_fps")
 
@@ -217,8 +223,13 @@ def channels(study: dict, cut, agg_i, sen_i, wm_i, det_fps, key_fps, ncam,
              mipi, cam_fps, dtype=np.float64) -> dict:
     """Eqs. 1-11 for configurations given by broadcastable per-axis
     arrays (indices into the study's node and memory axes, axis values
-    for the rates).  Returns ``avg_power``, ``latency`` and
-    ``mipi_bytes_per_s``; NaN marks an invalid configuration."""
+    for the rates).  Returns every channel of :data:`CHANNELS`.
+
+    NaN marks an invalid configuration (MRAM weights on a node without
+    MRAM, with an on-sensor deployment): ``sensor_memory`` and the
+    ``avg_power`` that sums it inherit NaN from the node table, the other
+    power groups never read it and stay finite, and the three non-power
+    channels carry it explicitly."""
     T = _tables()
     c = lambda a: np.asarray(a, dtype)       # noqa: E731
     cut = np.asarray(cut)
@@ -259,14 +270,15 @@ def channels(study: dict, cut, agg_i, sen_i, wm_i, det_fps, key_fps, ncam,
     sw = [s_wm[i][sen_i, wm_i] for i in range(4)]
     ss = [s_sram[i][sen_i] for i in range(4)]
     cap_a_s = (np.maximum(g(D["pre"], cd), g(K["pre"], ck)) + c(T.det_in))
-    pc_s, pm_s = _site(side("macs", cd, ck, True), side("wstream", cd, ck,
-                                                        True),
+    macs_sen = side("macs", cd, ck, True)
+    pc_s, pm_s = _site(macs_sen, side("wstream", cd, ck, True),
                        side("act", cd, ck, True), side("cyc_s", cd, ck, True),
                        s_fclk[sen_i], s_emac[sen_i], sw, ss,
                        g(D["wbytes"], cd) + g(K["wbytes"], ck), cap_a_s,
                        c(SENSOR_L1))
-    p_sensor = (np.where(has_sensor, pc_s * ncam, c(0))
-                + np.where(has_sensor, pm_s * ncam, c(0)))
+    sensor_compute = np.where(has_sensor, pc_s * ncam, c(0))
+    sensor_memory = np.where(has_sensor, pm_s * ncam, c(0))
+    p_sensor = sensor_compute + sensor_memory
     asr = [a_sram[i][agg_i] for i in range(4)]
     cap_w_a = ((g(D["wbytes"], nd) - g(D["wbytes"], cd))
                + (g(K["wbytes"], nk) - g(K["wbytes"], ck)))
@@ -278,7 +290,9 @@ def channels(study: dict, cut, agg_i, sen_i, wm_i, det_fps, key_fps, ncam,
                        side("cyc_a", cd, ck, False),
                        a_fclk[agg_i], a_emac[agg_i], asr, asr, cap_w_a,
                        cap_a_a, c(AGG_L1))
-    p_agg = (np.where(has_agg, pc_a, c(0)) + np.where(has_agg, pm_a, c(0)))
+    agg_compute = np.where(has_agg, pc_a, c(0))
+    agg_memory = np.where(has_agg, pm_a, c(0))
+    p_agg = agg_compute + agg_memory
 
     amort = np.minimum(c(1), det_fps / cam_fps)
     f_s, f_a = s_fclk[sen_i], a_fclk[agg_i]
@@ -292,8 +306,13 @@ def channels(study: dict, cut, agg_i, sen_i, wm_i, det_fps, key_fps, ncam,
                + t_key_agg)
     invalid = np.where(has_sensor, sw[0] * c(0), c(0))
     return {"avg_power": p_camera + p_utsv + p_mipi + p_sensor + p_agg,
-            "latency": latency + invalid,
-            "mipi_bytes_per_s": mipi_bps + invalid}
+            "camera": p_camera, "utsv": p_utsv, "mipi": p_mipi,
+            "sensor_compute": sensor_compute, "sensor_memory": sensor_memory,
+            "agg_compute": agg_compute, "agg_memory": agg_memory,
+            "mipi_bytes_per_s": mipi_bps + invalid,
+            "sensor_macs_per_s": (np.where(has_sensor, macs_sen * ncam, c(0))
+                                  + invalid),
+            "latency": latency + invalid}
 
 
 def shape_of(study: dict) -> tuple:
@@ -323,44 +342,87 @@ def feasible(values: dict, constraints: dict) -> np.ndarray:
     return ok
 
 
+#: Bins per pre-culled column of the staircase table, by the number of
+#: objectives: one column at d = 3, two at d = 4.
+_STAIR_BINS = {3: 2048, 4: 256}
+
+
+def _staircase(Q: np.ndarray) -> np.ndarray:
+    """Rows of ``Q``, sorted on column 0, that survive a cheap pre-cull.
+    After the sort every earlier row is <= in column 0.  Each of columns
+    1 to d-2 is binned: by value where it has at most the bin count of
+    distinct values (an earlier row in the same or a lower bin is <=
+    there), else by quantile (an earlier row in a strictly lower bin is
+    < there).  An earlier row that is so in every binned column, and <=
+    in column d-1 where some binned column made it strictly lower (else
+    < there), dominates.  A table of the lowest column d-1 seen per bin
+    answers that for a block of rows at a time by its prefix minimum."""
+    n, d = Q.shape
+    nb = _STAIR_BINS[d]
+    keys, dims, incl = [], [], []
+    for j in range(1, d - 1):
+        col = np.sort(Q[:, j])
+        vals = col[np.concatenate(([True], col[1:] != col[:-1]))]
+        if vals.size <= nb:
+            keys.append(np.searchsorted(vals, Q[:, j]))
+            dims.append(vals.size)
+            incl.append(1)
+        else:
+            edges = np.unique(col[np.linspace(0, n - 1, nb + 1).astype(int)])
+            keys.append(np.searchsorted(edges, Q[:, j], side="right"))
+            dims.append(edges.size + 1)
+            incl.append(0)
+    strict = not all(incl)
+    best = np.full(dims, np.inf)
+    alive = np.ones(n, bool)
+    step = 8192
+    for lo in range(0, n, step):
+        low = best
+        for ax in range(len(dims)):
+            low = np.minimum.accumulate(low, axis=ax)
+        # Shifted by one bin on each axis: entry b is the minimum over
+        # the bins below b, and entry b + 1 over those up to b.
+        low = np.pad(low, [(1, 0)] * len(dims), constant_values=np.inf)
+        at = tuple(k[lo:lo + step] for k in keys)
+        last = Q[lo:lo + step, -1]
+        seen = low[tuple(k + i for k, i in zip(at, incl))]
+        alive[lo:lo + step] = ~(seen <= last if strict else seen < last)
+        np.minimum.at(best, at, last)
+    return alive
+
+
+def _dominated(A: np.ndarray, B: np.ndarray) -> np.ndarray:
+    """Rows of ``B`` that some row of ``A`` dominates."""
+    return ((A[None] <= B[:, None]).all(-1)
+            & (A[None] < B[:, None]).any(-1)).any(1)
+
+
 def pareto_mask(P: np.ndarray) -> np.ndarray:
     """Exact non-dominated rows of a finite ``(n, d)`` matrix
     (minimisation; identical rows all survive)."""
     n, d = P.shape
     if n == 0:
         return np.zeros(0, bool)
-    order = np.lexsort(P.T[::-1])
+    idx = np.arange(n)
+    if d in _STAIR_BINS:
+        by0 = np.argsort(P[:, 0], kind="stable")
+        idx = by0[_staircase(P[by0])]
+    # In lexicographic order a row never dominates an earlier row, so
+    # every row kept is final, and the kept rows are the front so far.
+    order = idx[np.lexsort(P[idx].T[::-1])]
     Q = P[order]
-    alive = np.ones(n, bool)
-    if d == 3:
-        # Staircase pre-cull: after the sort every earlier row is <= in
-        # column 0, so an earlier row strictly lower in column 1 (lower
-        # quantile bin) and strictly lower in column 2 dominates.
-        edges = np.unique(np.quantile(Q[:, 1], np.linspace(0, 1, 2049)))
-        b = np.searchsorted(edges, Q[:, 1], side="right")
-        best = np.full(edges.size + 2, np.inf)
-        step = 8192
-        for lo in range(0, n, step):
-            bb, c2 = b[lo:lo + step], Q[lo:lo + step, 2]
-            excl = np.concatenate(([np.inf], np.minimum.accumulate(best)))
-            alive[lo:lo + step] = ~(excl[bb] < c2)
-            np.minimum.at(best, bb, c2)
-    idx = np.flatnonzero(alive)
-    keep = np.zeros(n, bool)
+    keep = np.zeros(order.size, bool)
     front = Q[:0]
-    for lo in range(0, idx.size, 512):
-        blk = Q[idx[lo:lo + 512]]
-        if front.shape[0]:
-            dom = ((front[None] <= blk[:, None]).all(-1)
-                   & (front[None] < blk[:, None]).any(-1)).any(1)
-            sel = np.flatnonzero(~dom)
-        else:
-            sel = np.arange(blk.shape[0])
+    for lo in range(0, order.size, 512):
+        blk = Q[lo:lo + 512]
+        # Only front rows <= the block's largest value in every column
+        # can dominate a row of the block.
+        near = front[(front <= blk.max(0)).all(1)]
+        sel = np.flatnonzero(~_dominated(near, blk)) if near.shape[0] \
+            else np.arange(blk.shape[0])
         B = blk[sel]
-        dom = ((B[None] <= B[:, None]).all(-1)
-               & (B[None] < B[:, None]).any(-1)).any(1)
-        sel = sel[~dom]
-        keep[idx[lo + sel]] = True
+        sel = sel[~_dominated(B, B)]
+        keep[lo + sel] = True
         front = np.concatenate([front, blk[sel]])
     mask = np.zeros(n, bool)
     mask[order[keep]] = True

@@ -20,7 +20,8 @@ The last line of standard output is the result object; earlier lines
 give the set-up breakdown, the compilations inside the window, each
 study's or the slowest tickets' times, the open loop's lateness, the
 host's stalls in the window and, with ``--trace 1``, each device's busy
-time.
+time, the program's spans and the chunk step's device time by named
+scope (``trace_spans.py``), and the traced run's end-to-end readings.
 Without a TPU, or with fewer chips than the cell asks for, or outside a
 checkout that holds the program's ``src/``, it exits non-zero and
 prints no result.
@@ -59,6 +60,7 @@ import numpy as np                                            # noqa: E402
 import compare                                                # noqa: E402
 import reference                                              # noqa: E402
 import trace as tracemod                                      # noqa: E402
+import trace_spans                                            # noqa: E402
 import traffic as traffic_mod                                 # noqa: E402
 
 
@@ -68,13 +70,22 @@ def log(**fields) -> None:
 
 
 def load_cell(root: Path, name: str):
-    """``(benchmark, cell, configuration, traffic)`` of a cell name."""
+    """``(benchmark, cell, configuration, traffic)`` of a cell name.  A
+    configuration whose objectives or constraints name a channel that
+    the plain reference cannot check is refused here, before any work."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cell = next((w for w in bench["workloads"] if w["name"] == name), None)
     if cell is None:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     cfg = next(c for c in bench["configs"] if c["name"] == cell["config"])
     config = json.loads((root / cfg["file"]).read_text())
+    unknown = [f for f in (*config["objectives"],
+                           *config.get("constraints", {}))
+               if f not in reference.CHANNELS]
+    if unknown:
+        raise ValueError(f"configuration {cfg['name']!r} names channels "
+                         f"the reference lacks: {', '.join(unknown)} (it "
+                         f"has {', '.join(reference.CHANNELS)})")
     traffic = json.loads((root / "bench" / "traffic"
                           / f"{cell['traffic']}.json").read_text())
     return bench, cell, config, traffic
@@ -197,6 +208,55 @@ class StallWatch:
                 "cpu_steal_s": (steal - self.steal0
                                 if steal is not None
                                 and self.steal0 is not None else None)}
+
+
+class StepWatch:
+    """The chunk-step programs a traced window runs, kept with their
+    argument shapes from the window's start, for their op-name paths:
+    a TPU trace's op events carry none, so :meth:`op_paths` compiles
+    each program once more after the window (a hit in the persistent
+    compilation cache) and reads them from its HLO text."""
+
+    def __init__(self):
+        from repro.core import backend
+        self._backend = backend
+        self._cached_step = backend.cached_step
+        self.steps: dict = {}
+        backend.cached_step = self._spy
+
+    def _spy(self, *a, **kw):
+        import jax
+        fn = self._cached_step(*a, **kw)
+
+        def step(*xs):
+            if id(fn) not in self.steps:
+                self.steps[id(fn)] = (fn, jax.tree_util.tree_map(
+                    self._abstract, xs))
+            return fn(*xs)
+        return step
+
+    @staticmethod
+    def _abstract(x):
+        """What the compiled program was keyed on, without the data: a
+        committed array's sharding and each value's weak type count."""
+        import jax
+        if not isinstance(x, jax.Array):
+            return x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, weak_type=x.weak_type,
+            sharding=x.sharding if x.committed else None)
+
+    def close(self) -> None:
+        self._backend.cached_step = self._cached_step
+
+    def op_paths(self) -> dict:
+        import jax
+        paths: dict = {}
+        with jax.enable_x64(True):
+            for fn, shapes in self.steps.values():
+                paths.update(trace_spans.hlo_op_paths(
+                    fn.lower(*shapes).compile().as_text()))
+        return paths
 
 
 def span(name: str):
@@ -395,6 +455,7 @@ def start_window(run, clock, trace_dir) -> None:
     """Close set-up and open the window (and the profiler's trace)."""
     if trace_dir:
         import jax
+        run["step_watch"] = StepWatch()
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0    # host spans only, no Python calls
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
@@ -411,6 +472,44 @@ def end_window(run, trace_dir) -> None:
     if trace_dir:
         import jax
         jax.profiler.stop_trace()
+        run["step_watch"].close()
+
+
+def reduce_trace(run, trace_dir, planes) -> None:
+    """``run["trace"]`` (``trace.py``'s reduction) and ``run["spans"]``
+    (``trace_spans.py``'s, with the step ops' named scopes) from the
+    window's trace, each logged; both ``None`` where no device op ran."""
+    clock = {"t": time.perf_counter()}
+
+    def lap(name):
+        t = time.perf_counter()
+        clock[name], clock["t"] = t - clock["t"], t
+
+    events = trace_spans.load(trace_dir)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    lap("load_s")
+    run["trace"] = tracemod.reduce(trace_spans.trace_events(events),
+                                   planes=planes)
+    lap("reduce_s")
+    run["spans"] = None
+    watch = run.pop("step_watch")
+    if not run["trace"]:
+        return
+    log(trace_devices=run["trace"]["devices"])
+    paths = watch.op_paths()
+    lap("recompile_s")
+    run["spans"] = red = trace_spans.reduce(
+        events, planes=planes, op_paths=paths, base=run["trace"])
+    lap("spans_s")
+    log(program_spans=red["program_spans"])
+    log(idle_by_span=red["idle_by_span"], idle_gaps=red["idle_gaps"],
+        ticket_idle=red["ticket_idle"])
+    log(scope_s=red["scope_s"], scope_leaf_s=red["scope_leaf_s"])
+    log(span_readings=trace_spans.readings(red, run))
+    del clock["t"]
+    log(trace_reduce=dict(clock, events=len(events),
+                          step_programs=len(watch.steps),
+                          op_paths=len(paths)))
 
 
 def check(answers, config, traffic, seed) -> dict:
@@ -530,12 +629,8 @@ def main(argv=None, require_chip: bool = True, root: Path = ROOT) -> int:
     log(window=summary, compiled_in_window=window["compiled"])
     log(host_stalls=run["stalls"])
     if trace_dir:
-        run["trace"] = tracemod.reduce(
-            tracemod.load(trace_dir),
-            planes=[tracemod.plane_name(d) for d in devs])
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        if run["trace"]:
-            log(trace_devices=run["trace"]["devices"])
+        reduce_trace(run, trace_dir,
+                     [tracemod.plane_name(d) for d in devs])
 
     numbers, n_checked = check(answers, config, traffic, args.seed)
     ok = compare.within(numbers) and not failures and n_checked > 0
@@ -556,7 +651,12 @@ def main(argv=None, require_chip: bool = True, root: Path = ROOT) -> int:
         device.update(busy_s=run["trace"]["busy_s"],
                       window_s=run["trace"]["window_s"])
         out["breakdown"] = {"device_ops": run["trace"]["device_ops"],
-                            "idle_gaps": run["trace"]["idle_gaps"]}
+                            "idle_gaps": run["spans"]["idle_gaps"]}
+    if args.trace:
+        log(traced_end_to_end={
+            m["name"]: read_metric(root, m["name"], run)
+            for m in cell_metrics(bench, cell["name"], False)
+            if m["name"] != "setup_s"})
     if failures:
         log(failures=failures[:5])
     log(answers_compared=n_checked)

@@ -88,6 +88,55 @@ def test_a_sound_run_is_correct_and_a_broken_one_is_not(root, monkeypatch,
     assert out["correct"] is (fault == "none"), out["checks"]
 
 
+def _fourth_channel_scaled(monkeypatch):
+    """On-sensor compute, the four-objective study's fourth channel,
+    off by one part in a million where the kernel produces it."""
+    import functools
+
+    from repro.core import backend, sweep
+
+    orig = sweep.vmapped_kernel
+
+    def scaled(S):
+        kernel = orig(S)
+
+        def wrong(*args):
+            out = dict(kernel(*args))
+            out["sensor_macs_per_s"] = out["sensor_macs_per_s"] * (1 + 1e-6)
+            return out
+        return wrong
+
+    monkeypatch.setattr(sweep, "vmapped_kernel", scaled)
+    monkeypatch.setattr(backend, "_cached_dense_eval", functools.lru_cache(
+        maxsize=32)(backend._cached_dense_eval.__wrapped__))
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 7])
+def test_the_four_objective_control_is_rejected(root, seed):
+    t = json.loads((root / "bench" / "traffic" / "batch.json").read_text())
+    nums = control.readings(tiny.TINY_4OBJ, t, seed, 2.0, 2)
+    assert not compare.within(nums)
+    assert nums["rel_err"] > 30 * compare.LIMITS["rel_err"]
+
+
+@pytest.mark.parametrize("fault", ["none", "fourth_channel_scaled",
+                                   "answer_altered"])
+def test_a_four_objective_cell_added_as_files_and_entries_alone(
+        root, monkeypatch, fault):
+    """The four-objective study with a budget on on-sensor memory runs
+    as data alone and is correct, and a fault in its fourth channel or
+    in an answer is not."""
+    from repro.core import backend
+
+    monkeypatch.setattr(backend, "_STEP_CACHE", OrderedDict())
+    if fault == "fourth_channel_scaled":
+        _fourth_channel_scaled(monkeypatch)
+    elif fault == "answer_altered":
+        _alter_answer(monkeypatch)
+    out, _ = tiny.run_cell(root, "tiny-4obj.batch", seed=17)
+    assert out["correct"] is (fault == "none"), out["checks"]
+
+
 CHILD = """
 import sys
 sys.path[:0] = [sys.argv[1], sys.argv[2]]

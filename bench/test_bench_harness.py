@@ -111,6 +111,50 @@ def test_a_directory_without_the_program_fails(tmp_path):
     assert p.stdout == ""
 
 
+REFUSE = """
+import sys
+sys.path.insert(0, sys.argv[1])
+import run
+from pathlib import Path
+rc = run.main(["--workload", "tiny-typo.batch", "--seed", "1", "--seconds",
+               "1"], require_chip=False, root=Path(sys.argv[2]))
+print("RC", rc, "JAX", "jax" in sys.modules)
+"""
+
+
+def test_a_channel_the_reference_lacks_is_refused_before_jax(tmp_path):
+    """A configuration that names a channel the plain reference cannot
+    check would fail only after its window: it is refused with exit
+    code 2 and the channel's name, before JAX is imported."""
+    root = tiny.make_root(tmp_path)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    config = dict(tiny.TINY, name="tiny-typo",
+                  objectives=["avg_power", "sensor_mac_per_s"],
+                  constraints={"lattency": {"uniform": [0.014, 0.016]}})
+    (root / "bench" / "configs" / "tiny-typo.json").write_text(
+        json.dumps(config))
+    bench["configs"].append({"name": "tiny-typo", "source": "CPU tests",
+                             "file": "bench/configs/tiny-typo.json",
+                             "reduced": [], "why": "CPU tests"})
+    bench["workloads"].append({"name": "tiny-typo.batch",
+                               "config": "tiny-typo", "traffic": "batch",
+                               "chips": 1, "why": "CPU tests"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", REFUSE, str(BENCH),
+                        str(root)], env=env, capture_output=True, text=True,
+                       timeout=300)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.strip().splitlines()[-1] == "RC 2 JAX False"
+    assert "sensor_mac_per_s" in p.stderr and "lattency" in p.stderr
+    p = subprocess.run([sys.executable, "bench/run.py", "--workload",
+                        "tiny-typo.batch", "--seed", "1", "--seconds", "1"],
+                       cwd=root, env=env, capture_output=True, text=True,
+                       timeout=300)
+    assert p.returncode == 2 and p.stdout == ""
+    assert "sensor_mac_per_s" in p.stderr
+
+
 def test_the_plain_reference_agrees_with_the_engine():
     """At a small size the float64 reference and the engine give the
     same configurations, counts and front."""

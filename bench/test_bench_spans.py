@@ -102,6 +102,10 @@ def test_every_number_trace_py_gives_stays_the_same(events, planes):
         assert new[k] == old[k], k
     assert ([g[1] for g in new["idle_gaps"]]
             == [g[1] for g in old["idle_gaps"]])
+    # As run.py calls it: trace.py's reduction of the events it keeps.
+    given = S.reduce(events, planes=planes,
+                     base=T.reduce(S.trace_events(events), planes=planes))
+    assert given == new
 
 
 @pytest.mark.parametrize("events", [synthetic(), served()])
@@ -229,33 +233,18 @@ def root(tmp_path_factory):
                                          ("tiny.open", "ticket_p50_s")])
 def test_the_traced_run_with_spans_leaves_run_py_as_it_was(root, cell,
                                                            metric):
-    """``trace_spans.main`` runs a cell through ``run.main`` unchanged
-    (a CPU trace has no device plane, so no span outputs), logs the
-    traced end-to-end reading, and puts back the hooks it used."""
-    import contextlib
-    import io
-    import json
-
-    import jax
+    """``run.py --trace 1`` logs the traced end-to-end reading (a CPU
+    trace has no device plane, so no span outputs) and puts back the
+    step hook it watched the window's programs with."""
     import run
+    import tiny
 
     from repro.core import backend as B
 
     hooks = (run.tracemod.load, run.read_metric, run.start_window,
              B.cached_step)
-    prev = jax.config.jax_compilation_cache_dir
-    buf = io.StringIO()
-    try:
-        with contextlib.redirect_stdout(buf):
-            rc = S.main(["--workload", cell, "--seed", "3", "--seconds",
-                         "1"], require_chip=False, root=root)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        from jax.experimental.compilation_cache import compilation_cache
-        compilation_cache.reset_cache()
-    assert rc == 0, buf.getvalue()
-    lines = [json.loads(x) for x in buf.getvalue().strip().splitlines()]
-    assert lines[-1]["correct"] is True
+    out, lines = tiny.run_cell(root, cell, seed=3, trace=1)
+    assert out["correct"] is True
     (e2e,) = [x["traced_end_to_end"] for x in lines
               if "traced_end_to_end" in x]
     assert e2e[metric] > 0
@@ -283,3 +272,173 @@ def test_op_paths_come_from_the_compiled_text_by_program_and_op():
     got = S.reduce(served(), op_paths=paths)["scope_s"]
     assert got == pytest.approx({"unscoped": 0.010, "eq1_11": 0.010,
                                  "dominance_filter": 0.005})
+
+
+NEW_READERS = {
+    "tiny.batch": ("filter_device_s_per_gconfig", "eval_device_s_per_gconfig"),
+    "tiny.open": ("queue_wait_p50_s", "journal_s_per_ticket",
+                  "codec_s_per_ticket", "executor_idle_p50_s")}
+
+
+@pytest.mark.parametrize("cell", list(NEW_READERS))
+def test_the_span_readers_read_a_recorded_trace_as_readings_does(
+        root, monkeypatch, cell):
+    """A traced run on the CPU records the program's and the harness's
+    host spans; a CPU trace has no device plane, so one is added: inside
+    each ``repro.stream.grid`` span, a step op under ``eq1_11`` for a
+    quarter of the span and one under ``dominance_filter`` for a tenth.
+    The step program is compiled once more for its op-name paths, two of
+    which name those ops.  Each new reader then reads what
+    ``trace_spans.readings`` gives of the run's spans."""
+    import jax
+    import tiny
+
+    plane = T.plane_name(jax.devices("cpu")[0])
+    load, hlo_op_paths = S.load, S.hlo_op_paths
+    compiled = {}
+
+    def with_device_plane(log_dir):
+        events = load(log_dir)
+        for _, _, name, s, d, *_ in list(events):
+            if name != "repro.stream.grid":
+                continue
+            prog = {"program": "jit_step"}
+            events += [
+                (plane, T.MODULE_LINE, "jit_step(1)", s + 0.2 * d, 0.5 * d),
+                (plane, T.OP_LINE, "fusion.eval", s + 0.2 * d, 0.25 * d,
+                 prog, T.OP_LINE),
+                (plane, T.OP_LINE, "fusion.filter", s + 0.5 * d, 0.1 * d,
+                 prog, T.OP_LINE)]
+        return events
+
+    def with_two_ops(text):
+        paths = hlo_op_paths(text)
+        compiled.update(paths)
+        return {**paths,
+                ("jit_step", "fusion.eval"): "jit(step)/eq1_11/mul",
+                ("jit_step", "fusion.filter"):
+                    "jit(step)/chunk_partials/dominance_filter/lt"}
+
+    monkeypatch.setattr(S, "load", with_device_plane)
+    monkeypatch.setattr(S, "hlo_op_paths", with_two_ops)
+    out, lines = tiny.run_cell(root, cell, seed=7, trace=1)
+    assert out["correct"] is True
+    # The window's step programs were compiled again, and their text
+    # names the step's scopes.
+    scopes = {S.scope_of(p) for p in compiled.values()}
+    assert {"eq1_11", "dominance_filter", "decode_gather"} <= scopes
+    (want,) = [x["span_readings"] for x in lines if "span_readings" in x]
+    for name in NEW_READERS[cell]:
+        assert out["metrics"][name]["value"] == want[name] > 0, name
+    assert out["breakdown"]["idle_gaps"]
+    if cell == "tiny.batch":
+        assert want["filter_device_s_per_gconfig"] == pytest.approx(
+            0.4 * want["eval_device_s_per_gconfig"])
+    else:
+        assert want["executor_idle_p50_s"] > 0
+        assert any(g[0].startswith("repro.")
+                   for g in out["breakdown"]["idle_gaps"])
+
+
+def test_the_window_step_compiles_again_from_the_cache(tmp_path):
+    """The step program a batch window runs, compiled once more after the
+    window for its op-name paths, is a hit in the persistent compilation
+    cache: its arguments' weak types and committed shardings are kept,
+    which its key holds."""
+    import json
+
+    import jax
+    import numpy as np
+    import run
+    import traffic as TM
+
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.core import stream
+
+    config = json.loads((BENCH / "configs"
+                         / "ht-partition-1e7.json").read_text())
+    study, budgets = TM.draw_study(config, np.random.default_rng(1))
+    kw = dict(TM.stream_kwargs(study), constraints=budgets,
+              objectives=tuple(config["objectives"]),
+              top_k=config["top_k"], devices=jax.devices()[:1])
+    plan = stream.plan_stream(**kw)
+    assert plan.scan > 1                     # the batch step, jit(step)
+    quantum = plan.chunk * plan.scan
+    events = []
+
+    def listen(event, **_):
+        events.append(event)
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    try:
+        for k, v in zip(keys, (str(tmp_path), 0, 0)):
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+        stream.stream_grid(**kw, flat_range=(plan.n_total - 2 * quantum,
+                                             plan.n_total))
+        watch = run.StepWatch()
+        try:
+            stream.stream_grid(**kw, flat_range=(
+                plan.n_total - 3 * quantum, plan.n_total))
+        finally:
+            watch.close()
+        jax.monitoring.register_event_listener(listen)
+        try:
+            paths = watch.op_paths()
+        finally:
+            jax.monitoring.unregister_event_listener(listen)
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert len(watch.steps) == 1
+    assert "/jax/compilation_cache/cache_hits" in events
+    assert "/jax/compilation_cache/cache_misses" not in events
+    assert {"eq1_11", "dominance_filter"} <= {S.scope_of(p)
+                                              for p in paths.values()}
+
+
+def _stack_self_times(ops):
+    """The op-by-op walk ``trace_spans._self_times`` replaced."""
+    ops = sorted(ops, key=lambda o: (o[0], -o[1]))
+    own = [o[1] - o[0] for o in ops]
+    leaf = [True] * len(ops)
+    stack = []
+    for i, (s, e, _) in enumerate(ops):
+        while stack and ops[stack[-1]][1] <= s:
+            stack.pop()
+        if stack:
+            p = stack[-1]
+            own[p] -= min(e, ops[p][1]) - s
+            leaf[p] = False
+        stack.append(i)
+    return [(ops[i][2], own[i], leaf[i]) for i in range(len(ops))]
+
+
+@pytest.mark.parametrize("nested", [True, False])
+def test_self_times_match_an_op_by_op_walk(nested):
+    """Nested ops as a loop and its body give them, and ops that only
+    overlap, with ties of start and end."""
+    import numpy as np
+
+    rng = np.random.default_rng(5)
+    if nested:
+        ops, t = [], 0.0
+        for _ in range(300):
+            n = int(rng.integers(0, 4))
+            ops.append((t, t + 10.0 * (n + 1)))
+            ops += [(t + 10.0 * i, t + 10.0 * i + rng.integers(1, 11))
+                    for i in range(n)]
+            t += 10.0 * (n + 1) + rng.integers(0, 3)
+    else:
+        s = rng.integers(0, 500, 2000).astype(float)
+        ops = list(zip(s, s + rng.integers(1, 40, 2000)))
+    s0 = np.array([o[0] for o in ops])
+    s1 = np.array([o[1] for o in ops])
+    order, own, leaf = S._self_times(s0, s1)
+    want = _stack_self_times([(a, b, i) for i, (a, b) in enumerate(ops)])
+    assert order.tolist() == [w[0] for w in want]
+    assert own.tolist() == [w[1] for w in want]
+    assert leaf.tolist() == [w[2] for w in want]

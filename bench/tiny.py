@@ -2,8 +2,9 @@
 
 :func:`make_root` copies ``BENCHMARK.json`` and ``bench/`` into a
 directory, links the program's ``src/``, and adds a 43,520-config
-configuration with a batch, an open-loop and a four-device cell, as
-files and entries alone — the way a later change adds a cell.
+configuration with a batch, an open-loop and a four-device cell, and a
+four-objective study of the same space with a batch cell, as files and
+entries alone — the way a later change adds a cell.
 """
 
 from __future__ import annotations
@@ -34,6 +35,15 @@ TINY = {
     "jitter": {"detnet_fps": 0.1, "camera_fps": 0.1},
     "reduced": [], "assumed": []}
 
+#: The same space with on-sensor compute as a fourth objective and a
+#: budget on on-sensor memory power.
+TINY_4OBJ = dict(
+    TINY, name="tiny-4obj",
+    objectives=["avg_power", "latency", "mipi_bytes_per_s",
+                "sensor_macs_per_s"],
+    constraints=dict(TINY["constraints"],
+                     sensor_memory={"uniform": [1.0e-3, 2.0e-3]}))
+
 TINY_OPEN = {"kind": "open_loop", "rate_per_s": 8.0,
              "query": {"detnet_fps": 2, "num_cameras": 1,
                        "mipi_energy_scale": 1, "camera_fps": 3},
@@ -48,17 +58,21 @@ def make_root(dest: Path) -> Path:
     shutil.copytree(BENCH, dest / "bench", ignore=shutil.ignore_patterns(
         ".jax_cache", "__pycache__"))
     os.symlink(REPO / "src", dest / "src")
-    (dest / "bench" / "configs" / "tiny.json").write_text(json.dumps(TINY))
     (dest / "bench" / "traffic" / "tiny-open.json").write_text(
         json.dumps(TINY_OPEN))
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    bench["configs"].append({"name": "tiny", "source": TINY["source"],
-                             "file": "bench/configs/tiny.json",
-                             "reduced": [], "why": "CPU tests"})
-    for name, traffic, chips in (("tiny.batch", "batch", 1),
-                                 ("tiny.open", "tiny-open", 1),
-                                 ("tiny.batch-4", "batch", 4)):
-        bench["workloads"].append({"name": name, "config": "tiny",
+    for config in (TINY, TINY_4OBJ):
+        path = f"bench/configs/{config['name']}.json"
+        (dest / path).write_text(json.dumps(config))
+        bench["configs"].append({"name": config["name"],
+                                 "source": config["source"], "file": path,
+                                 "reduced": [], "why": "CPU tests"})
+    for name, config, traffic, chips in (
+            ("tiny.batch", "tiny", "batch", 1),
+            ("tiny.open", "tiny", "tiny-open", 1),
+            ("tiny.batch-4", "tiny", "batch", 4),
+            ("tiny-4obj.batch", "tiny-4obj", "batch", 1)):
+        bench["workloads"].append({"name": name, "config": config,
                                    "traffic": traffic, "chips": chips,
                                    "why": "CPU tests"})
         for m in bench["end_to_end"] + bench["per_layer"]:

@@ -21,6 +21,8 @@ import os
 import re
 from collections import defaultdict
 
+import numpy as np
+
 OP_LINE = "XLA Ops"
 MODULE_LINE = "XLA Modules"
 WINDOW_SPAN = "bench.window"
@@ -61,13 +63,22 @@ def load(log_dir: str) -> list:
 
 
 def _union(intervals):
-    merged = []
-    for s, e in sorted(intervals):
-        if merged and s <= merged[-1][1]:
-            merged[-1][1] = max(merged[-1][1], e)
-        else:
-            merged.append([s, e])
-    return merged
+    """Sorted, disjoint ``[start, end]`` lists covering ``intervals``
+    (pairs); touching intervals merge."""
+    a = np.asarray(intervals, float).reshape(-1, 2)
+    return union_of(a[:, 0], a[:, 1])
+
+
+def union_of(s0, s1) -> list:
+    """:func:`_union` of the intervals ``[s0, s1]`` given as arrays."""
+    if not s0.size:
+        return []
+    order = np.lexsort((s1, s0))
+    start, end = s0[order], np.maximum.accumulate(s1[order])
+    first = np.flatnonzero(np.concatenate(([True], start[1:] > end[:-1])))
+    last = np.concatenate((first[1:] - 1, [start.size - 1]))
+    return [[a, b] for a, b in zip(start[first].tolist(),
+                                   end[last].tolist())]
 
 
 def plane_name(device) -> str:
@@ -101,15 +112,22 @@ def reduce(events: list, planes=None) -> dict | None:
     by_plane = defaultdict(list)
     for ev in dev_ev:
         by_plane[ev[0]].append(ev)
+    op_names: dict = {}        # an event's name parsed once per name
     for plane in sorted(set(by_plane) | set(planes or ())):
         iv = []
         for _, line, name, s, d in by_plane[plane]:
-            s0, s1 = max(s, w0), min(s + d, w1)
+            s0 = s if s > w0 else w0
+            s1 = s + d
+            if s1 > w1:
+                s1 = w1
             if s1 <= s0:
                 continue
             if line == OP_LINE:
                 iv.append((s0, s1))
-                ops[op_name(name)] += (s1 - s0) * 1e-9
+                key = op_names.get(name)
+                if key is None:
+                    key = op_names[name] = op_name(name)
+                ops[key] += (s1 - s0) * 1e-9
             else:
                 programs[program_name(name)] += (s1 - s0) * 1e-9
         merged = _union(iv)

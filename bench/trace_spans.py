@@ -34,12 +34,9 @@ maps each instruction of a module to its op-name path, and
 * ``program_spans``: per-name count and total of the ``repro.*`` spans
   in the window, and the ten longest with their thread and ticket.
 
-    python3 bench/trace_spans.py --workload <cell> --seed <n> --seconds <s>
-
-runs a cell with ``--trace 1`` through ``run.py`` unchanged and logs
-these outputs, and :func:`readings` of them, before its result line;
-it compiles the window's step programs once more after the run (a hit
-in the persistent compilation cache) for their HLO text.
+``run.py --trace 1`` loads the trace with :func:`load`, logs these
+outputs before its result line, and reads the per-layer metrics of
+:func:`readings` from them (``metrics/<name>.py``).
 """
 
 from __future__ import annotations
@@ -52,6 +49,8 @@ import sys
 import warnings
 from collections import defaultdict
 from pathlib import Path
+
+import numpy as np
 
 BENCH = Path(__file__).resolve().parent
 if str(BENCH) not in sys.path:
@@ -69,6 +68,7 @@ STEP = ("jit_step", "jit_one", "jit_call_wrapped")
 OUTSIDE = "outside any span"
 UNSCOPED = "unscoped"
 WORKER_SPAN = "repro.service.execute"
+_NO_META: dict = {}
 _SCOPE_RE = re.compile(r"(?:^|/)(" + "|".join(SCOPES) + r")(?=/|$)")
 _HLO_OP = re.compile(
     r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*?op_name="([^"]*)"', re.M)
@@ -125,8 +125,10 @@ def load(log_dir: str) -> list:
                     continue
                 k = bisect.bisect_right(starts, s) - 1
                 prog = mods[k][2] if k >= 0 and s < mods[k][1] else ""
-                out.append((plane.name, line.name, e.name, s, d,
-                            progs.setdefault(prog, {"program": prog}),
+                meta = progs.get(prog)
+                if meta is None:
+                    meta = progs[prog] = {"program": prog}
+                out.append((plane.name, line.name, e.name, s, d, meta,
                             thread))
     return out
 
@@ -152,23 +154,37 @@ def scope_of(op_path: str) -> str:
     return found[-1] if found else UNSCOPED
 
 
-def _self_times(ops):
-    """``[(op, self_ns, leaf)]`` of one device's ops ``(s, e, op)``:
-    each op's duration less the parts of it that ops nested in it
-    cover."""
-    ops = sorted(ops, key=lambda o: (o[0], -o[1]))
-    own = [o[1] - o[0] for o in ops]
-    leaf = [True] * len(ops)
-    stack = []
-    for i, (s, e, _) in enumerate(ops):
-        while stack and ops[stack[-1]][1] <= s:
-            stack.pop()
-        if stack:
-            p = stack[-1]
-            own[p] -= min(e, ops[p][1]) - s
-            leaf[p] = False
-        stack.append(i)
-    return [(ops[i][2], own[i], leaf[i]) for i in range(len(ops))]
+def _self_times(s0, s1):
+    """``(order, own, leaf)`` of one device's ops, from their starts and
+    ends: the order that sorts them by start, the longest first on a
+    tie, and in that order each op's duration less the parts of it that
+    ops nested in it cover, and whether none is.  An op's parent is the
+    latest earlier op, in that order, still running when it starts."""
+    order = np.lexsort((-s1, s0))
+    S0, S1 = s0[order], s1[order]
+    n = S0.size
+    own = S1 - S0
+    # top[k][p]: the latest end among the 2^k ops before position p.
+    top = [np.concatenate(([-np.inf], S1))]
+    while (1 << len(top)) <= n:
+        h = 1 << (len(top) - 1)
+        t = top[-1].copy()
+        t[h:] = np.maximum(t[h:], top[-1][:-h])
+        top.append(t)
+    # Step back over every block of ops that ended by the op's start.
+    pos = np.arange(n)
+    for k in range(len(top) - 1, -1, -1):
+        back = pos - (1 << k)
+        ok = back >= 0
+        ok[ok] = top[k][pos[ok]] <= S0[ok]
+        pos = np.where(ok, back, pos)
+    child = np.flatnonzero(pos > 0)
+    parent = pos[child] - 1
+    np.subtract.at(own, parent, np.minimum(S1[child], S1[parent])
+                   - S0[child])
+    leaf = np.ones(n, bool)
+    leaf[parent] = False
+    return order, own, leaf
 
 
 def _overlap(merged, starts, s, e):
@@ -181,63 +197,85 @@ def _overlap(merged, starts, s, e):
     return cov
 
 
-def reduce(events: list, planes=None, op_paths=None) -> dict | None:
+def reduce(events: list, planes=None, op_paths=None,
+           base=None) -> dict | None:
     """``trace.reduce`` of the same trace, with the idle gaps named by
     the spans the idle time is charged to, and the outputs the module
     docstring lists; ``None`` where ``trace.reduce`` gives ``None``.
     ``op_paths`` (:func:`hlo_op_paths` of the step programs) gives the
-    ops' op-name paths; without it every step op is unscoped."""
-    base = T.reduce([ev[:5] for ev in events], planes=planes)
+    ops' op-name paths; without it every step op is unscoped.  ``base``
+    is ``trace.reduce``'s result where the caller has it already (it
+    costs seconds on a long trace)."""
+    if base is None:
+        base = T.reduce([ev[:5] for ev in events], planes=planes)
     if base is None:
         return None
-    ev = [_fields(e) for e in events]
-    win = [(s, s + d) for p, _, n, s, d, _, _ in ev
-           if p.startswith("/host:") and n == T.WINDOW_SPAN]
-    dev = [e for e in ev if e[0].startswith("/device:")
-           and (planes is None or e[0] in planes)]
+    host = [_fields(e) for e in events if e[0].startswith("/host:")]
+    by_plane = defaultdict(list)        # each device's op line
+    for e in events:
+        if (e[0].startswith("/device:") and e[1] == T.OP_LINE
+                and (planes is None or e[0] in planes)):
+            by_plane[e[0]].append(e)
+    win = [(s, s + d) for _, _, n, s, d, _, _ in host
+           if n == T.WINDOW_SPAN]
     if win:
         w0, w1 = win[0]
     else:
+        dev = [e for e in events if e[0].startswith("/device:")
+               and (planes is None or e[0] in planes)]
         w0 = min(e[3] for e in dev)
         w1 = max(e[3] + e[4] for e in dev)
-    spans = sorted(((s, s + d, n, th, m) for p, _, n, s, d, m, th in ev
-                    if p.startswith("/host:") and n != T.WINDOW_SPAN
+    spans = sorted(((s, s + d, n, th, m) for _, _, n, s, d, m, th in host
+                    if n != T.WINDOW_SPAN
                     and n.startswith(("bench.", "repro."))),
                    key=lambda sp: sp[0])
     workers = {sp[3] for sp in spans if sp[2] == WORKER_SPAN}
 
-    by_plane = defaultdict(list)
-    for e in dev:
-        by_plane[e[0]].append(e)
     busy = {}
     gaps = []                  # (start, end, plane), as trace.reduce
-    scope_s = defaultdict(float)
-    leaf_s = defaultdict(float)
+    names = SCOPES + (UNSCOPED,)
     scopes: dict = {}
+    codes, owns, leaves = [], [], []
     for plane in sorted(set(by_plane) | set(planes or ())):
-        iv, ops = [], []
-        for _, line, name, s, d, meta, _ in by_plane[plane]:
-            s0, s1 = max(s, w0), min(s + d, w1)
-            if s1 <= s0 or line != T.OP_LINE:
-                continue
-            iv.append((s0, s1))
-            ops.append((s0, s1, (meta, name)))
-        merged = T._union(iv)
+        evs = by_plane[plane]
+        n = len(evs)
+        start = np.fromiter((e[3] for e in evs), float, n)
+        s1 = np.minimum(start + np.fromiter((e[4] for e in evs), float, n),
+                        w1)
+        s0 = np.maximum(start, w0)
+        kept = np.flatnonzero(s1 > s0)
+        s0, s1 = s0[kept], s1[kept]
+        merged = T.union_of(s0, s1)
         busy[plane] = (merged, [m[0] for m in merged])
         edges = [w0] + [x for m in merged for x in m] + [w1]
         gaps += [(s, e, plane) for s, e in zip(edges[::2], edges[1::2])
                  if e > s]
-        for (meta, name), own, is_leaf in _self_times(ops):
-            if meta.get("program") not in STEP:
-                continue
-            key = (meta["program"], name)
-            sc = scopes.get(key)
-            if sc is None:          # once per op, not once per event
-                sc = scopes[key] = scope_of((op_paths or {}).get(
-                    (key[0], T.op_name(name)), ""))
-            scope_s[sc] += own * 1e-9
-            if is_leaf:
-                leaf_s[sc] += own * 1e-9
+        code = np.empty(kept.size, int)
+        for i, k in enumerate(kept.tolist()):
+            e = evs[k]
+            meta = e[5] if len(e) > 5 else _NO_META
+            c = scopes.get((id(meta), e[2]))
+            if c is None:           # once per op, not once per event
+                prog = meta.get("program")
+                c = scopes[id(meta), e[2]] = -1 if prog not in STEP \
+                    else names.index(scope_of((op_paths or {}).get(
+                        (prog, T.op_name(e[2])), "")))
+            code[i] = c
+        order, own, leaf = _self_times(s0, s1)
+        codes.append(code[order])
+        owns.append(own)
+        leaves.append(leaf)
+    # Summed over devices, in the order the ops ran on each.
+    code = np.concatenate(codes) if codes else np.empty(0, int)
+    own = np.concatenate(owns) if owns else np.empty(0)
+    leaf = np.concatenate(leaves) if leaves else np.empty(0, bool)
+    step = code >= 0
+    scope_s, leaf_s = {}, {}
+    for out, sel in ((scope_s, step), (leaf_s, step & leaf)):
+        n = np.bincount(code[sel], minlength=len(names))
+        total = np.bincount(code[sel], weights=own[sel] * 1e-9,
+                            minlength=len(names))
+        out.update({names[i]: float(total[i]) for i in np.flatnonzero(n)})
 
     # Cut every idle interval at span edges; charge each piece.
     cuts = sorted({x for sp in spans for x in sp[:2]})
@@ -293,8 +331,8 @@ def reduce(events: list, planes=None, op_paths=None) -> dict | None:
         idle_gaps=idle_gaps,
         idle_by_span=dict(sorted(charged.items(), key=lambda kv: -kv[1])),
         ticket_idle=_ticket_idle(spans, labelled),
-        scope_s=dict(scope_s),
-        scope_leaf_s=dict(leaf_s),
+        scope_s=scope_s,
+        scope_leaf_s=leaf_s,
         span_s=dict(span_s),
         queued_s=queued,
         executor_idle_s=exec_idle,
@@ -363,87 +401,3 @@ def readings(red: dict | None, run: dict) -> dict:
         if gconfigs and scope_s.get(scope):
             out[name] = scope_s[scope] / gconfigs
     return out
-
-
-def main(argv=None, **run_kw) -> int:
-    """``run.main`` with ``--trace 1`` (and ``run_kw``), logging this
-    module's outputs and readings, and the cell's end-to-end metrics as
-    the traced run reads them, before the result line."""
-    import run as R
-
-    box: dict = {}
-    steps: dict = {}
-    read_metric, start_window = R.read_metric, R.start_window
-
-    def load_all(log_dir):
-        box["events"] = load(log_dir)
-        return trace_events(box["events"])
-
-    def watch_steps(*args, **kw):
-        # Keep each step program the window runs, and its argument
-        # shapes, for its op-name paths where the trace has none.
-        import jax
-        from repro.core import backend as B
-
-        box["cached_step"] = cached_step = B.cached_step
-
-        def spy(*a, **k):
-            fn = cached_step(*a, **k)
-
-            def step(*xs):
-                if id(fn) not in steps:
-                    steps[id(fn)] = (fn, jax.tree_util.tree_map(
-                        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                        xs))
-                return fn(*xs)
-            return step
-
-        B.cached_step = spy
-        return start_window(*args, **kw)
-
-    def step_op_paths():
-        import jax
-        paths: dict = {}
-        with jax.enable_x64(True):
-            for fn, shapes in steps.values():
-                paths.update(hlo_op_paths(
-                    fn.lower(*shapes).compile().as_text()))
-        return paths
-
-    def read_and_log(root, name, run):
-        if "logged" not in box:
-            box["logged"] = True
-            tr = run.get("trace") or {}
-            red = reduce(box.get("events", []),
-                         planes=list(tr.get("devices", ())) or None,
-                         op_paths=step_op_paths() if tr else None)
-            if red is not None:
-                R.log(program_spans=red["program_spans"])
-                R.log(idle_by_span=red["idle_by_span"],
-                      idle_gaps=red["idle_gaps"],
-                      ticket_idle=red["ticket_idle"])
-                R.log(scope_s=red["scope_s"],
-                      scope_leaf_s=red["scope_leaf_s"])
-                R.log(span_readings=readings(red, run))
-            R.log(traced_end_to_end={
-                m: read_metric(root, m, run)
-                for m in ("configs_per_s", "ticket_p50_s", "ticket_p95_s")
-                if (root / "bench" / "metrics" / f"{m}.py").exists()})
-        return read_metric(root, name, run)
-
-    load_trace = R.tracemod.load
-    R.tracemod.load, R.read_metric = load_all, read_and_log
-    R.start_window = watch_steps
-    try:
-        return R.main(list(sys.argv[1:] if argv is None else argv)
-                      + ["--trace", "1"], **run_kw)
-    finally:
-        R.tracemod.load, R.read_metric = load_trace, read_metric
-        R.start_window = start_window
-        if "cached_step" in box:
-            from repro.core import backend as B
-            B.cached_step = box["cached_step"]
-
-
-if __name__ == "__main__":
-    sys.exit(main())
